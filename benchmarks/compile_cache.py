@@ -29,7 +29,6 @@ import os
 import shutil
 import subprocess
 import sys
-import tempfile
 
 _MARK = "CCBENCH:"
 
@@ -73,7 +72,10 @@ def _child(cache_dir: str) -> None:
 
 
 def _spawn(cache_dir: str) -> dict:
+    # a fresh process per restart; it runs on the CPU (this process may
+    # already hold the accelerator), and the rows say so
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in ("src", env.get("PYTHONPATH", ""), ".") if p)
     r = subprocess.run(
@@ -91,8 +93,11 @@ def _spawn(cache_dir: str) -> dict:
 
 def run() -> list:
     from benchmarks.common import row
+    from repro.core.compile_cache import CHECKOUT
 
-    cache_dir = tempfile.mkdtemp(prefix="repro-compile-cache-bench-")
+    # a fixed directory, emptied before the cold child and after the warm
+    cache_dir = str(CHECKOUT / ".jax_cache" / "compile_cache_bench")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     try:
         cold = _spawn(cache_dir)
         warm = _spawn(cache_dir)
@@ -116,7 +121,7 @@ def run() -> list:
             f"deserialize_s={warm['cc']['deserialize_seconds']:.2f}"),
         row("compile_cache/restart_speedup", 0,
             f"speedup={speedup:.1f}x;pass_ge_5x={speedup >= 5.0};"
-            f"token_parity={parity}"),
+            f"token_parity={parity};platform=cpu"),
     ]
 
 

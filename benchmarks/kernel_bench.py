@@ -1,9 +1,9 @@
 """Kernel accounting: unpack_apply + bitlinear vs dense baselines.
 
-Interpret-mode wall time on CPU is not TPU-meaningful, so the *derived*
-column carries the structural story: HBM bytes per op and the modelled
-v5e speedup for the memory-bound regimes the kernels target (decode GEMV,
-loader reconstruction).  Correctness vs ref.py is asserted inline.
+Interpret-mode wall time on CPU is not a device time, so the *derived*
+column carries only what shapes determine: HBM bytes per op for the
+memory-bound regimes the kernels target (decode GEMV, loader
+reconstruction).  Correctness vs ref.py is asserted inline.
 """
 from __future__ import annotations
 
@@ -13,7 +13,6 @@ import numpy as np
 
 from benchmarks.common import row, timeit
 from repro.core import delta as D
-from repro.distributed.hlo_analysis import HBM_BW, PEAK_FLOPS
 from repro.kernels import ops as K
 from repro.kernels import ref as R
 
@@ -41,9 +40,8 @@ def run() -> list:
         K.unpack_apply(packed, v, wb, mode="row")), n=3)
     # loader path: reads Wb (2B/elt) + mask (1/8 B/elt), writes 2B/elt
     bytes_moved = d_out * d_in * (2 + 2 + 1 / 8)
-    t_v5e = bytes_moved / HBM_BW
     out.append(row("kernel/unpack_apply_1024sq", t * 1e6,
-                   f"hbm_bytes={int(bytes_moved)};v5e_us={t_v5e*1e6:.1f};"
+                   f"hbm_bytes={int(bytes_moved)};"
                    f"vs_dense_copy={(d_out*d_in*4)/bytes_moved:.2f}x"))
 
     m = 8  # decode GEMV regime

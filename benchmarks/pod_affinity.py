@@ -132,7 +132,10 @@ def run() -> list:
     if len(jax.devices()) >= 8:
         return _measure()
     # re-exec with forced host devices (mirrors launch/dryrun.py)
+    # the child forces host devices: it runs on the CPU (this process may
+    # already hold the accelerator), and its rows say so
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -144,7 +147,7 @@ def run() -> list:
     if r.returncode != 0:
         tail = (r.stderr or r.stdout).strip().splitlines()[-1:]
         raise RuntimeError(f"pod_affinity subprocess failed: {tail}")
-    return [ln for ln in r.stdout.splitlines()
+    return [ln + ";platform=cpu" for ln in r.stdout.splitlines()
             if ln.startswith("pod_affinity/")]
 
 

@@ -29,6 +29,11 @@ Prints ``name,us_per_call,derived`` CSV rows.  Sections:
            (DESIGN.md §16)
   roofline dry-run roofline terms per (arch × shape × mesh)
 
+Sections that need several devices (sharded_serving, pod_affinity,
+shard_map_kernels, compile_cache) run them in child processes pinned to
+the CPU with forced host devices — this process may hold the
+accelerator — and tag their rows ``platform=cpu``.
+
 ``--strict`` exits nonzero when any section errors (CI gate — by default
 a crash is swallowed into a ``*/ERROR,0,...`` CSV row and the driver
 exits 0, which hides regressions).  ``--sections a,b`` runs a subset.
@@ -137,6 +142,8 @@ def main() -> None:
                          "verdicts) to this path")
     args = ap.parse_args()
 
+    from repro.core.compile_cache import use_jax_cache
+    use_jax_cache()
     from benchmarks import (admission_overlap, axis_stats, compile_cache,
                             continuous_batching, fused_serving, kernel_bench,
                             load_time, pod_affinity, quantized_base,

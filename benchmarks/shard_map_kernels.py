@@ -140,7 +140,10 @@ def run() -> list:
     import jax
     if len(jax.devices()) >= 4:
         return _measure()
+    # the child forces host devices: it runs on the CPU (this process may
+    # already hold the accelerator), and its rows say so
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -152,7 +155,7 @@ def run() -> list:
     if r.returncode != 0:
         tail = (r.stderr or r.stdout).strip().splitlines()[-1:]
         raise RuntimeError(f"shard_map subprocess failed: {tail}")
-    return [ln for ln in r.stdout.splitlines()
+    return [ln + ";platform=cpu" for ln in r.stdout.splitlines()
             if ln.startswith("shard_map_kernels/")]
 
 

@@ -26,11 +26,9 @@ executable:
   and read as a miss — never an exception on the serving path.
 
 Where executable serialization is unavailable (some backends refuse
-``serialize``), the cache degrades to JAX's own persistent compilation
-cache: ``enable_xla_fallback`` points ``jax_compilation_cache_dir`` at
-a subdirectory, so ``.compile()`` still skips XLA's backend work on a
-warm restart even when we cannot persist the loaded executable
-ourselves.
+``serialize``), ``.compile()`` still finds JAX's own persistent
+compilation cache, which :func:`use_jax_cache` places once per process
+for the entry points.
 
 Observability: ``stats`` counts hits / misses / compiles /
 compile-seconds / corrupt entries / env mismatches — surfaced through
@@ -127,7 +125,7 @@ class CompileCache:
     never raise on the serving path.  ``load_or_compile`` is the
     one-stop wrapped→lowered→compiled helper callers use."""
 
-    def __init__(self, path, *, xla_fallback: bool = True):
+    def __init__(self, path):
         self.path = pathlib.Path(path)
         self.path.mkdir(parents=True, exist_ok=True)
         self.stats = {"hits": 0, "misses": 0, "puts": 0,
@@ -135,21 +133,6 @@ class CompileCache:
                       "deserialize_seconds": 0.0,
                       "corrupt": 0, "env_mismatch": 0,
                       "serialize_failures": 0}
-        if xla_fallback:
-            self._enable_xla_fallback()
-
-    def _enable_xla_fallback(self) -> None:
-        """Point JAX's own persistent compilation cache at a subdir so
-        even executables we cannot serialize ourselves (and plain jits
-        that never route through here) compile warm on restart."""
-        try:
-            xla_dir = self.path / "xla"
-            xla_dir.mkdir(exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", str(xla_dir))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except Exception:
-            pass   # older jaxlib without the knobs: executables only
 
     # -- keys ---------------------------------------------------------------
     def key(self, *parts) -> str:
@@ -205,7 +188,7 @@ class CompileCache:
         """Serialize ``compiled`` under the key; atomic (tmp +
         os.replace) so a crashed writer leaves a clean miss, not a torn
         entry.  Returns False when this executable refuses
-        serialization (the XLA fallback dir still covers it)."""
+        serialization (JAX's own persistent cache may still hold it)."""
         key = (key_or_parts if isinstance(key_or_parts, str)
                else self.key(*key_or_parts))
         try:
@@ -246,6 +229,26 @@ class CompileCache:
         self.stats["compile_seconds"] += time.perf_counter() - t0
         self.put(key, compiled)
         return compiled, "compiled"
+
+
+# -- JAX's own persistent cache ----------------------------------------------
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_jax_cache() -> str:
+    """Place JAX's persistent compilation cache for an entry point
+    (chip_smoke.py, launch/serve.py, benchmarks/run.py) and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and nothing is set here; otherwise the cache is the fixed
+    ``<checkout>/.jax_cache`` — a fixed path, because the directory is
+    part of what makes a later process find the entries again."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # -- process default ---------------------------------------------------------
@@ -321,13 +324,7 @@ class CachedCallable:
                                         args, ctx=None) \
                 if not kwargs else self._load_kw(cc, akey, args, kwargs)
             self._exe[akey] = exe
-        try:
-            return exe(*args)
-        except Exception:
-            # aval-compatible but call-incompatible executable (layout
-            # drift, committed-device mismatch): correctness beats cache
-            self._exe.pop(akey, None)
-            return self.jitted(*args, **kwargs)
+        return exe(*args)
 
     def aot(self, *args) -> str:
         """Force the compiled stage for these (possibly abstract) args

@@ -125,7 +125,11 @@ def quantize_base(params, param_shardings=None):
     untouched — embeddings, norms, convs stay full precision), the
     matching shardings tree with target leaves upgraded via
     :func:`quant_sharding` (None in, None out), and a byte accounting
-    dict (``fp_bytes`` / ``int8_bytes`` / ``ratio`` over targets)."""
+    dict (``fp_bytes`` / ``int8_bytes`` / ``ratio`` over targets).
+
+    Targets that arrive already quantized (a base built int8 from the
+    start, ``launch/build.init_base``) pass through with their shardings;
+    their ``fp_bytes`` count the bf16 weight they stand for."""
     from repro.core.calibration import (flatten_params, is_target,
                                         unflatten_like)
     flat = flatten_params(params)
@@ -134,8 +138,12 @@ def quantize_base(params, param_shardings=None):
     out = {}
     for path, leaf in flat.items():
         if path in targets:
-            qw = quantize_weight(leaf)
-            fp_bytes += leaf.size * leaf.dtype.itemsize
+            if is_quant(leaf):
+                qw = leaf
+                fp_bytes += leaf.q.size * 2
+            else:
+                qw = quantize_weight(leaf)
+                fp_bytes += leaf.size * leaf.dtype.itemsize
             q_bytes += qw.nbytes()
             out[path] = qw
         else:
@@ -145,7 +153,8 @@ def quantize_base(params, param_shardings=None):
     if param_shardings is not None:
         sflat = flatten_params(param_shardings)
         for path in targets:
-            sflat[path] = quant_sharding(sflat[path], flat[path].ndim)
+            if not is_quant(sflat[path]):
+                sflat[path] = quant_sharding(sflat[path], flat[path].ndim)
         qsh = unflatten_like(param_shardings, sflat)
     stats = {"targets": len(targets), "fp_bytes": int(fp_bytes),
              "int8_bytes": int(q_bytes),

@@ -48,6 +48,24 @@ def _sha(arr: np.ndarray) -> str:
     return hashlib.sha256(np.ascontiguousarray(arr).tobytes()).hexdigest()[:16]
 
 
+def _head(leaf, n: int = 64) -> np.ndarray:
+    """The first ``n`` values of ``leaf`` in row-major order.  A leaf over
+    1 MiB is sliced where it lives — index 0 on the outer dims and only
+    the rows the values span along the next — so a device-resident weight
+    sends a few hundred bytes to the host instead of itself; a smaller
+    one is copied whole, which costs less than compiling the slice."""
+    shape = tuple(leaf.shape)
+    if int(np.prod(shape)) * np.dtype(leaf.dtype).itemsize <= 1 << 20:
+        return np.asarray(jax.device_get(leaf)).ravel()[:n]
+    d, inner = len(shape), 1
+    while d > 0 and inner < n:
+        d -= 1
+        inner *= shape[d]
+    sub = leaf[(0,) * d] if d else leaf
+    sub = sub[:-(-n // (inner // shape[d]))]
+    return np.asarray(jax.device_get(sub)).ravel()[:n]
+
+
 def base_fingerprint(base_params) -> str:
     """Cheap fingerprint of the base checkpoint (shapes + sampled bytes)."""
     h = hashlib.sha256()
@@ -58,8 +76,7 @@ def base_fingerprint(base_params) -> str:
                  base_params)[0])):
         h.update(path.encode())
         h.update(str(leaf.shape).encode())
-        arr = np.asarray(jax.device_get(leaf)).ravel()
-        h.update(arr[:64].tobytes())
+        h.update(_head(leaf).tobytes())
     return h.hexdigest()[:16]
 
 

@@ -102,7 +102,7 @@ def cross_pod_grad_mean(grads, mesh, axis_name: str = "pod"):
     """Apply compressed_psum leaf-wise over the pod axis (grads replicated
     within pod, differing across pods)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def fn(*leaves):
         return tuple(compressed_psum(l, axis_name) for l in leaves)
@@ -110,5 +110,5 @@ def cross_pod_grad_mean(grads, mesh, axis_name: str = "pod"):
     flat, treedef = jax.tree.flatten(grads)
     specs = tuple(P() for _ in flat)  # replicated per pod-shard
     out = shard_map(fn, mesh=mesh, in_specs=specs, out_specs=specs,
-                    check_rep=False)(*flat)
+                    check_vma=False)(*flat)
     return jax.tree.unflatten(treedef, list(out))

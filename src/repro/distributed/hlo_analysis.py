@@ -11,10 +11,10 @@ all-to-all / collective-permute, applying ring-algorithm wire factors:
   all-to-all        (n−1)/n · bytes
   collective-permute        bytes
 
-Hardware model (TPU v5e, from the assignment): 197 TFLOP/s bf16 per chip,
-819 GB/s HBM, ~50 GB/s/link ICI.  The collective term conservatively
-charges all traffic to ONE link (a 2D-torus chip has more); the roofline
-table notes this.
+Hardware model: ``PEAKS``, keyed by ``device_kind`` as JAX reports it.  A
+device missing from the table is an error, never a default.  The
+collective term conservatively charges all traffic to ONE link (a
+2D-torus chip has more); the roofline table notes this.
 """
 from __future__ import annotations
 
@@ -22,9 +22,20 @@ import dataclasses
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12       # bf16 per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link
+# Per-chip peaks.  TPU v5e: Google Cloud documentation, "TPU v5e" —
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect (~50 GB/s per link over four links).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak FLOP/s and bytes/s of one chip of ``device_kind``."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks recorded for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -129,7 +140,8 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
 
 def roofline_terms(flops_per_device: float, bytes_per_device: float,
                    wire_bytes_per_device: float,
-                   model_flops_per_device: float = 0.0) -> dict:
+                   model_flops_per_device: float = 0.0, *,
+                   device_kind: str) -> dict:
     """Three roofline terms in seconds (per assignment formulae) plus:
 
     * ``useful_ratio``  = MODEL_FLOPS / HLO_FLOPs  (remat / redundancy waste)
@@ -137,14 +149,15 @@ def roofline_terms(flops_per_device: float, bytes_per_device: float,
       compiled program could reach if the dominant term ran at peak — the
       static-analysis stand-in for measured MFU (CPU-only container).
     """
-    compute_s = flops_per_device / PEAK_FLOPS
-    memory_s = bytes_per_device / HBM_BW
-    collective_s = wire_bytes_per_device / ICI_BW
+    pk = peaks(device_kind)
+    compute_s = flops_per_device / pk["flops"]
+    memory_s = bytes_per_device / pk["hbm_bw"]
+    collective_s = wire_bytes_per_device / pk["ici_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
-    model_s = model_flops_per_device / PEAK_FLOPS
+    model_s = model_flops_per_device / pk["flops"]
     return {**terms, "dominant": dominant, "roofline_s": bound,
             "model_flops_s": model_s,
             "useful_ratio": (model_flops_per_device / flops_per_device

@@ -243,7 +243,7 @@ def local_top_k(score: jax.Array, k: int, axes: Sequence[Optional[str]]
     mesh, rules = st
     spec = resolve_spec(score.shape, axes, rules, mesh)
     out_spec = P(*(list(spec)[:-1] + [None]))
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     return shard_map(lambda s: tuple(jax.lax.top_k(s, k)), mesh=mesh,
                      in_specs=(spec,), out_specs=(out_spec, out_spec),
-                     check_rep=False)(score)
+                     check_vma=False)(score)

@@ -1,297 +1,121 @@
-"""Pallas TPU kernel: fused on-the-fly delta GEMM  y = x @ (v⊙unpack(B)+W_b)ᵀ.
+"""Pallas TPU kernel: the sign GEMM of a (banked) per-axis delta.
 
-The paper (§4, last paragraph) notes an "on-the-fly variant [that] could
-apply [deltas] dynamically in each forward pass and avoid switch costs, but
-would introduce runtime overhead unless supported by fused GEMM kernels".
-This is that kernel, adapted TPU-natively:
+Every delta GEMM in this repo factors the variant weight
+Ŵ = (v_row ⊕ v_col) ⊙ S + W_b as
 
-* GPU approach would be XNOR/popcount bit-tricks; on TPU the MXU wants a
-  dense bf16 tile anyway, so we unpack the (bn × bk/8) uint8 tile to ±1 in
-  VMEM (VPU shifts), fuse the per-axis FMA to form Ŵ-tile, and issue a
-  *single* MXU dot per tile — identical FLOPs to the dense GEMM.
-* The win is bandwidth: decode-time GEMV is HBM-bound; streaming the delta
-  costs 1/16 of the base-weight bytes, so serving a *different* variant per
-  step costs ~6% extra traffic instead of 2× (two dense weight reads) or a
-  full dense re-materialisation per swap.
+    x · Ŵᵀ = x · W_bᵀ  +  v_row ⊙ (x · Sᵀ)  +  (x ⊙ v_col) · Sᵀ
 
-Shapes:  x (M, K) · packed (N, K/8) · w_base (N, K) · y (M, N).
-Per-axis scale v2d pre-reshaped by ops.py: row (N, 1) · col (1, K) ·
-scalar (1, 1).  (row scales output features = rows of W.)
+The base term is an ordinary XLA matmul (``ops.base_matmul``, shared with
+the no-overlay path, fp or int8 base alike).  This kernel computes the two
+sign terms straight from the packed 1-bit planes, for a BANK of variants:
+row m of x contracts against bank slot ``vidx[m]`` (slot-0 vectors are
+zero, so base rows get an exactly-zero delta).  A single fused variant is
+the one-slot bank.
 
-Grid (M/bm, N/bn, K/bk), K innermost; fp32 accumulation directly in the
-output block (out dtype fp32; caller casts).
+Layout (bit-compatible with ``core/delta.pack_signs``): byte i of a packed
+row holds columns 8i..8i+7, bit j ↔ column 8i+j.  Bit-plane j of a
+(bn, bk/8) packed block is therefore a lane-dense ±1 block over columns
+j, j+8, j+16, ...; the wrapper permutes the small activation x (and v_col)
+to match — ``xp[j, m, i] = x[m, 8i+j]`` — so each plane is one MXU dot and
+no lane interleave ever happens in VMEM.
+
+Grid (M/bm, N/bn, K/bk, V), slot axis innermost; the output block
+accumulates in fp32 over K blocks and slots.  Slots absent from an M block
+are skipped through a scalar-prefetched occupancy table, so a decode step
+unpacks only the variants its rows use.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.unpack_apply import _unpack_tile
+from jax.experimental.pallas import tpu as pltpu
 
 PACK = 8
 
-
-def _kernel(x_ref, packed_ref, v_ref, wb_ref, out_ref):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    signs = _unpack_tile(packed_ref[...], jnp.float32)      # (bn, bk)
-    v = v_ref[...].astype(jnp.float32)                      # (bn,1)|(1,bk)|(1,1)
-    w_hat = (v * signs + wb_ref[...].astype(jnp.float32))   # (bn, bk)
-    x = x_ref[...].astype(jnp.float32)                      # (bm, bk)
-    out_ref[...] += jax.lax.dot_general(
-        x, w_hat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+# scoped-VMEM ceiling handed to Mosaic; ops._gemm_blocks sizes blocks to
+# stay well under it (v5e has 128 MiB of VMEM per core)
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _kernel_axes(x_ref, packed_ref, vr_ref, vc_ref, wb_ref, out_ref):
-    """Dual-axis variant: effective scale v[n,k] = v_row[n] + v_col[k].
-
-    The serving overlay (models/delta_overlay.py) zeroes the UNSELECTED
-    axis vector per matrix, so the sum reduces to exactly the selected
-    per-axis scale — one kernel covers row, col and scalar entries, and
-    the axis choice stays a plain array (scan/vmap-able over stacked
-    layers) instead of a static mode argument.
-    """
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    signs = _unpack_tile(packed_ref[...], jnp.float32)      # (bn, bk)
-    v = (vr_ref[...].astype(jnp.float32)                    # (bn, 1)
-         + vc_ref[...].astype(jnp.float32))                 # (1, bk)
-    w_hat = (v * signs + wb_ref[...].astype(jnp.float32))   # (bn, bk)
-    x = x_ref[...].astype(jnp.float32)                      # (bm, bk)
-    out_ref[...] += jax.lax.dot_general(
-        x, w_hat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _kernel_q8(x_ref, packed_ref, v_ref, wq_ref, ws_ref, out_ref):
-    """Int8-base variant of ``_kernel``: the (bn, bk) base tile arrives
-    int8 and is dequantized in VMEM against the per-output-channel fp16
-    scale (a (bn, 1) broadcast) before the delta FMA — one dequant +
-    delta-apply per tile, same single MXU dot."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    signs = _unpack_tile(packed_ref[...], jnp.float32)      # (bn, bk)
-    v = v_ref[...].astype(jnp.float32)
-    wb = (wq_ref[...].astype(jnp.float32)
-          * ws_ref[...].astype(jnp.float32))                # (bn, bk)
-    w_hat = v * signs + wb
-    x = x_ref[...].astype(jnp.float32)
-    out_ref[...] += jax.lax.dot_general(
-        x, w_hat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _kernel_axes_q8(x_ref, packed_ref, vr_ref, vc_ref, wq_ref, ws_ref,
-                    out_ref):
-    """Int8-base variant of ``_kernel_axes`` (same in-tile dequant)."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    signs = _unpack_tile(packed_ref[...], jnp.float32)      # (bn, bk)
-    v = (vr_ref[...].astype(jnp.float32)
-         + vc_ref[...].astype(jnp.float32))
-    wb = (wq_ref[...].astype(jnp.float32)
-          * ws_ref[...].astype(jnp.float32))                # (bn, bk)
-    w_hat = v * signs + wb
-    x = x_ref[...].astype(jnp.float32)
-    out_ref[...] += jax.lax.dot_general(
-        x, w_hat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _kernel_axes_banked(x_ref, vidx_ref, packed_ref, vr_ref, vc_ref, wb_ref,
-                        out_ref):
-    """Banked variant: overlay operands carry a leading bank axis V and each
-    batch ROW selects its own bank slot via ``variant_idx`` (slot 0 = base,
-    whose packed/vector slots are zero, so v_eff = 0 and Ŵ-row = W_b).
-
-    Mixed-variant decode is a GEMV per row (HBM-bound, M = batch slots), so
-    instead of one MXU dot per variant (V× FLOPs when every row differs) the
-    kernel gathers each row's PACKED tile + axis vectors from the bank in
-    VMEM, unpacks per row, and contracts on the VPU — work is O(M·bn·bk),
-    independent of bank size.  The whole bank block rides in VMEM: packed is
-    1/16 the bytes of the base tile per slot, so even V=16 costs ~2× the
-    base-weight tile footprint.
-    """
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vidx = vidx_ref[...][:, 0]                              # (bm,)
-    packed = jnp.take(packed_ref[...], vidx, axis=0)        # (bm, bn, bk/8)
-    bm, bn, bkp = packed.shape
-    signs = _unpack_tile(packed.reshape(bm * bn, bkp),
-                         jnp.float32).reshape(bm, bn, bkp * PACK)
-    v = (jnp.take(vr_ref[...], vidx, axis=0).astype(jnp.float32)   # (bm,bn,1)
-         + jnp.take(vc_ref[...], vidx, axis=0).astype(jnp.float32))
-    w_hat = v * signs + wb_ref[...].astype(jnp.float32)[None]      # (bm,bn,bk)
-    x = x_ref[...].astype(jnp.float32)                             # (bm, bk)
-    out_ref[...] += jnp.einsum("mnk,mk->mn", w_hat, x,
+def _dot_nt(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
-def _kernel_axes_banked_q8(x_ref, vidx_ref, packed_ref, vr_ref, vc_ref,
-                           wq_ref, ws_ref, out_ref):
-    """Int8-base variant of ``_kernel_axes_banked``: the shared base tile
-    dequantizes ONCE per tile (not per bank slot) before the per-row
-    broadcast — banked extras and the bank gather are unchanged."""
-    k = pl.program_id(2)
+def _kernel(used_ref, vidx_ref, xp_ref, packed_ref, vr_ref, vc_ref, out_ref):
+    i, kk, s = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    nslots = pl.num_programs(3)
 
-    @pl.when(k == 0)
+    @pl.when((kk == 0) & (s == 0))
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    vidx = vidx_ref[...][:, 0]                              # (bm,)
-    packed = jnp.take(packed_ref[...], vidx, axis=0)        # (bm, bn, bk/8)
-    bm, bn, bkp = packed.shape
-    signs = _unpack_tile(packed.reshape(bm * bn, bkp),
-                         jnp.float32).reshape(bm, bn, bkp * PACK)
-    v = (jnp.take(vr_ref[...], vidx, axis=0).astype(jnp.float32)
-         + jnp.take(vc_ref[...], vidx, axis=0).astype(jnp.float32))
-    wb = (wq_ref[...].astype(jnp.float32)
-          * ws_ref[...].astype(jnp.float32))                # (bn, bk)
-    w_hat = v * signs + wb[None]                            # (bm, bn, bk)
-    x = x_ref[...].astype(jnp.float32)                      # (bm, bk)
-    out_ref[...] += jnp.einsum("mnk,mk->mn", w_hat, x,
-                               preferred_element_type=jnp.float32)
+    @pl.when(used_ref[i * nslots + s] > 0)
+    def _accumulate():
+        p = packed_ref[0].astype(jnp.int32)                 # (bn, bk/8)
+        cdt = xp_ref.dtype
+
+        def plane_step(j, acc):
+            xs, xc = acc                                    # x·Sᵀ, (x⊙v_col)·Sᵀ
+            plane = (((p >> j) & 1) * 2 - 1).astype(cdt)
+            xj = xp_ref[j]                                  # (bm, bk/8)
+            xcj = (xj.astype(jnp.float32) * vc_ref[0, j]).astype(cdt)
+            return xs + _dot_nt(xj, plane), xc + _dot_nt(xcj, plane)
+
+        zero = jnp.zeros(out_ref.shape, jnp.float32)
+        xs, xc = jax.lax.fori_loop(0, PACK, plane_step, (zero, zero))
+        contrib = vr_ref[0] * xs + xc                       # vr (1, bn)
+        out_ref[...] += jnp.where(vidx_ref[...] == s, contrib, 0.0)
 
 
-def bitlinear_axes_banked_p(x: jax.Array, vidx: jax.Array, packed: jax.Array,
-                            vr2d: jax.Array, vc2d: jax.Array,
-                            w_base: jax.Array, *, block_m: int, block_n: int,
-                            block_k: int, interpret: bool,
-                            w_scale: jax.Array = None) -> jax.Array:
-    """x (M, K) · vidx (M, 1) int32 · packed (V, N, K/8) · vr2d (V, N, 1) ·
-    vc2d (V, 1, K) · w_base (N, K) -> y (M, N) fp32.  ``w_scale`` (N, 1)
-    fp16 selects the int8-base kernel (w_base is then int8)."""
-    m, k_dim = x.shape
-    n, _ = w_base.shape
-    nbank = packed.shape[0]
-    assert k_dim % PACK == 0 and block_k % PACK == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    assert vidx.shape == (m, 1) and vidx.dtype == jnp.int32
-    assert vr2d.shape == (nbank, n, 1) and vc2d.shape == (nbank, 1, k_dim)
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-
-    in_specs = [
-        pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((block_m, 1), lambda i, j, kk: (i, 0)),
-        pl.BlockSpec((nbank, block_n, block_k // PACK),
-                     lambda i, j, kk: (0, j, kk)),
-        pl.BlockSpec((nbank, block_n, 1), lambda i, j, kk: (0, j, 0)),
-        pl.BlockSpec((nbank, 1, block_k), lambda i, j, kk: (0, 0, kk)),
-        pl.BlockSpec((block_n, block_k), lambda i, j, kk: (j, kk)),
-    ]
-    operands = [x, vidx, packed, vr2d, vc2d, w_base]
-    kernel = _kernel_axes_banked
-    if w_scale is not None:
-        assert w_scale.shape == (n, 1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda i, j, kk: (j, 0)))
-        operands.append(w_scale)
-        kernel = _kernel_axes_banked_q8
-
+def sign_gemm_p(x: jax.Array, vidx: jax.Array, packed: jax.Array,
+                v_row: jax.Array, v_col: jax.Array, *, block_m: int,
+                block_n: int, block_k: int, interpret: bool) -> jax.Array:
+    """x (M, K) · vidx (M,) int32 · packed (V, N, K/8) uint8 · v_row (V, N)
+    · v_col (V, K) -> Σ-delta (M, N) fp32.  ``block_k`` counts unpacked
+    columns (its packed block is block_k/8 bytes wide)."""
+    m, k = x.shape
+    nslots, n, kp = packed.shape
+    assert k == kp * PACK and block_k % PACK == 0
+    assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0
+    assert vidx.shape == (m,)
+    assert v_row.shape == (nslots, n) and v_col.shape == (nslots, k)
+    bkp = block_k // PACK
+    nm = m // block_m
+    # bf16 activations feed the MXU as bf16 (±1 is exact there, products
+    # accumulate in fp32); anything wider runs fp32
+    cdt = jnp.bfloat16 if x.dtype == jnp.bfloat16 else jnp.float32
+    xp = x.astype(cdt).reshape(m, kp, PACK).transpose(2, 0, 1)
+    vcp = (v_col.astype(jnp.float32).reshape(nslots, kp, PACK)
+           .transpose(0, 2, 1)[:, :, None])
+    vidx = vidx.astype(jnp.int32)
+    used = (vidx.reshape(nm, block_m, 1)
+            == jnp.arange(nslots, dtype=jnp.int32)).any(axis=1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nm, n // block_n, kp // bkp, nslots),
+        in_specs=[
+            pl.BlockSpec((block_m, 1), lambda i, j, kk, s, u: (i, 0)),
+            pl.BlockSpec((PACK, block_m, bkp),
+                         lambda i, j, kk, s, u: (0, i, kk)),
+            pl.BlockSpec((1, block_n, bkp),
+                         lambda i, j, kk, s, u: (s, j, kk)),
+            pl.BlockSpec((1, 1, block_n), lambda i, j, kk, s, u: (s, 0, j)),
+            pl.BlockSpec((1, PACK, 1, bkp),
+                         lambda i, j, kk, s, u: (s, 0, 0, kk)),
+        ],
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, kk, s, u: (i, j)),
+    )
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
+        _kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*operands)
-
-
-def bitlinear_axes_p(x: jax.Array, packed: jax.Array, vr2d: jax.Array,
-                     vc2d: jax.Array, w_base: jax.Array, *, block_m: int,
-                     block_n: int, block_k: int, interpret: bool,
-                     w_scale: jax.Array = None) -> jax.Array:
-    """``w_scale`` (N, 1) fp16 selects the int8-base kernel: w_base is
-    then the int8 payload, dequantized per tile in VMEM."""
-    m, k_dim = x.shape
-    n, _ = w_base.shape
-    assert k_dim % PACK == 0 and block_k % PACK == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    assert vr2d.shape == (n, 1) and vc2d.shape == (1, k_dim)
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-
-    in_specs = [
-        pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((block_n, block_k // PACK), lambda i, j, kk: (j, kk)),
-        pl.BlockSpec((block_n, 1), lambda i, j, kk: (j, 0)),
-        pl.BlockSpec((1, block_k), lambda i, j, kk: (0, kk)),
-        pl.BlockSpec((block_n, block_k), lambda i, j, kk: (j, kk)),
-    ]
-    operands = [x, packed, vr2d, vc2d, w_base]
-    kernel = _kernel_axes
-    if w_scale is not None:
-        assert w_scale.shape == (n, 1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda i, j, kk: (j, 0)))
-        operands.append(w_scale)
-        kernel = _kernel_axes_q8
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(*operands)
-
-
-def bitlinear_p(x: jax.Array, packed: jax.Array, v2d: jax.Array,
-                w_base: jax.Array, *, block_m: int, block_n: int,
-                block_k: int, interpret: bool,
-                w_scale: jax.Array = None) -> jax.Array:
-    m, k_dim = x.shape
-    n, _ = w_base.shape
-    assert k_dim % PACK == 0 and block_k % PACK == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-
-    vn, vk = v2d.shape  # (N,1) | (1,K) | (1,1)
-    v_block = (block_n if vn > 1 else 1, block_k if vk > 1 else 1)
-
-    def v_index(i, j, kk):
-        return (j if vn > 1 else 0, kk if vk > 1 else 0)
-
-    in_specs = [
-        pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((block_n, block_k // PACK), lambda i, j, kk: (j, kk)),
-        pl.BlockSpec(v_block, v_index),
-        pl.BlockSpec((block_n, block_k), lambda i, j, kk: (j, kk)),
-    ]
-    operands = [x, packed, v2d, w_base]
-    kernel = _kernel
-    if w_scale is not None:
-        assert w_scale.shape == (n, 1)
-        in_specs.append(pl.BlockSpec((block_n, 1), lambda i, j, kk: (j, 0)))
-        operands.append(w_scale)
-        kernel = _kernel_q8
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(*operands)
+    )(used.reshape(-1).astype(jnp.int32), vidx.reshape(m, 1), xp, packed,
+      v_row.astype(jnp.float32).reshape(nslots, 1, n), vcp)

@@ -2,15 +2,16 @@
 
 The sharded serving path (DESIGN.md §11) jits whole decode steps with
 explicit in/out shardings and lets GSPMD partition everything inside —
-including the fused delta kernels.  That works for the interpret-mode (CPU)
+including the delta kernels.  That works for the interpret-mode (CPU)
 lowering, but on a real TPU mesh a ``pl.pallas_call`` is a single opaque
 custom call: GSPMD cannot slice into it, so the global kernel would force
 full all-gathers of the very weight tiles the mesh exists to split.  This
-module is the explicit alternative: wrap each fused delta GEMM in
-``shard_map`` so every device runs the Pallas kernel on its OWN weight /
-overlay tile, with block sizes picked from shard-local dims and the one
-required collective (a psum over the contracted model axis, for
-column-sharded weights) stated in the open.
+module is the explicit alternative: wrap each delta GEMM's sign-plane
+term (the base matmul is plain XLA, which GSPMD partitions) in
+``shard_map`` so every device runs the Pallas kernel on its OWN overlay
+tile, with block sizes picked from shard-local dims and the one required
+collective (a psum over the contracted model axis, for column-sharded
+weights) stated in the open.
 
 Axis derivation (one source of truth, shared with the storage layer):
 
@@ -34,11 +35,12 @@ behaviour for A/B parity and latency comparisons
 (benchmarks/shard_map_kernels.py; engine ``kernel_dispatch="gspmd"``).
 
 Fallback contract: every entry point returns ``None`` when a per-shard
-lowering is not possible — unknown weight axes, a shard-local K tile that
-is not a multiple of the packing width (``_pick_block`` now refuses those
-instead of silently picking a global-only block), or nothing to shard —
-and the ops wrapper then serves the global kernel unchanged.  Dispatch is
-an optimisation layer: it must never change results, only layouts.
+lowering is not possible — unknown weight axes, or a shard-local K tile
+that is not a multiple of the packing width (``_pick_block`` refuses
+those) — and the ops wrapper then serves the global kernel and counts
+the decline (``note_decline``): on a TPU mesh that kernel cannot be
+partitioned, so a decline is a fault to fix, not a path to serve from.
+Dispatch must never change results, only layouts.
 """
 from __future__ import annotations
 
@@ -51,7 +53,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 PACK = 8
@@ -148,8 +150,8 @@ def plan_matmul(mesh, rules: dict, waxes, m: Optional[int], n: int,
         # to the packing width — _pick_block rightly refuses such dims, so
         # this matmul stays on the global path
         return None
-    if m_part is None and o_part is None and i_part is None:
-        return None             # fully replicated: global path IS local
+    # a fully replicated plan still runs per device under shard_map: the
+    # global kernel is an opaque custom call GSPMD cannot place
     return Plan(m_part=m_part, o_part=o_part, i_part=i_part)
 
 
@@ -170,7 +172,7 @@ _MEMO_CAP = 256
 _compiled: "collections.OrderedDict" = collections.OrderedDict()
 memo_stats = {"hits": 0, "misses": 0, "evictions": 0,
               "persist_hits": 0, "persist_compiles": 0,
-              "compile_seconds": 0.0}
+              "compile_seconds": 0.0, "declined": 0}
 
 
 def memo_info() -> dict:
@@ -258,74 +260,18 @@ def _avals(*arrays) -> tuple:
     return tuple((tuple(a.shape), jnp.dtype(a.dtype).name) for a in arrays)
 
 
-def _unwrap_quant(w):
-    """(payload, scale-or-None) of a base-weight operand — the dispatch
-    twin of ``ops._unwrap_quant`` (duck-typed; ops imports this module,
-    not the reverse).  An int8 base adds one per-output-channel scale
-    operand to the shard_map, sharded like the out dim it scales; the
-    int8 payload aval makes the memo key dtype-distinct on its own."""
-    if getattr(w, "__quant_leaf__", False):
-        return w.q, w.scale
-    return w, None
+def note_decline() -> None:
+    """Count one delta matmul traced inside a mesh context that did not
+    get a per-shard kernel (unknown weight axes, a shard-local K that is
+    not a packing-width multiple): it runs as the global kernel, which
+    GSPMD cannot partition on a TPU mesh.  Read through ``memo_info()``;
+    the four-chip smoke run fails on any."""
+    memo_stats["declined"] += 1
 
 
 # ---------------------------------------------------------------------------
 # shard_map'd entry points (ops.py routes here; every one may return None)
 # ---------------------------------------------------------------------------
-
-def bitlinear_axes(st, x: jax.Array, packed: jax.Array, v_row: jax.Array,
-                   v_col: jax.Array, w_base,
-                   waxes) -> Optional[jax.Array]:
-    """shard_map'd fused y = x @ ((v_row ⊕ v_col) ⊙ unpack(B) + W_b)ᵀ.
-
-    ``w_base`` may be a QuantWeight: the per-output-channel scale rides
-    as one extra operand sharded with the out dim and each shard's
-    Pallas call dequantizes its own int8 tile in VMEM."""
-    mesh, rules = st
-    wq, ws = _unwrap_quant(w_base)
-    *lead, k = x.shape
-    n = wq.shape[0]
-    x2 = x.reshape(-1, k)
-    plan = plan_matmul(mesh, rules, waxes, x2.shape[0], n, k)
-    if plan is None:
-        return None
-    mp, op, ip = plan.m_part, plan.o_part, plan.i_part
-
-    def shard_fn(x2, pk, vr, vc, wb, *ws_op):
-        # import from the SUBMODULES directly: the kernels package
-        # re-exports same-named jitted functions over the module attrs
-        from repro.kernels.bitlinear import bitlinear_axes_p
-        import repro.kernels.ops as O
-        lm, lk = x2.shape
-        ln = wb.shape[0]
-        y = bitlinear_axes_p(
-            x2, pk, vr.reshape(ln, 1), vc.reshape(1, lk), wb,
-            block_m=O._pick_block(lm, O._TILE_M),
-            block_n=O._pick_block(ln, O._TILE_N),
-            block_k=O._pick_block(lk, O._TILE_K, multiple=PACK),
-            interpret=O._interpret(),
-            w_scale=ws_op[0].reshape(ln, 1) if ws_op else None)
-        if plan.psum_axes:
-            y = jax.lax.psum(y, plan.psum_axes)
-        return y
-
-    vr = v_row.reshape(n)
-    vc = v_col.reshape(k)
-    in_specs = (P(mp, ip), P(op, ip), P(op), P(ip), P(op, ip))
-    operands = (x2, packed, vr, vc, wq)
-    if ws is not None:
-        in_specs += (P(op),)
-        operands += (ws.reshape(n),)
-    fn = _cached_jit(
-        ("axes", mesh, plan, _avals(*operands)),
-        lambda: shard_map(
-            shard_fn, mesh=mesh,
-            in_specs=in_specs,
-            out_specs=P(mp, op),    # op is None whenever ip carried model
-            check_rep=False))
-    y = fn(*operands)
-    return y.astype(x.dtype).reshape(*lead, n)
-
 
 def _bank_part(mesh, rules: dict, nb: int, plan: Plan):
     """Mesh partition of the bank slot axis under the active rules (None
@@ -356,138 +302,95 @@ def _axes_linear_index(names: tuple):
     return idx
 
 
-def bitlinear_axes_banked(st, x: jax.Array, variant_idx: jax.Array,
-                          packed: jax.Array, v_row: jax.Array,
-                          v_col: jax.Array, w_base: jax.Array,
-                          waxes) -> Optional[jax.Array]:
-    """shard_map'd mixed-variant fused GEMM: overlay leaves carry a leading
-    bank axis; each device gathers its rows' slots from its OWN weight
-    tile's bank — admission stays collective-free and so does the per-row
-    gather.
+def sign_delta(st, x2: jax.Array, vidx: jax.Array, packed: jax.Array,
+               v_row: jax.Array, v_col: jax.Array,
+               waxes) -> Optional[jax.Array]:
+    """shard_map'd sign delta of a bank: x2 (M, K) · vidx (M,) · packed
+    (V, N, K/8) · v_row (V, N) · v_col (V, K) -> (M, N) fp32.  Each device
+    reads its rows' slots from its OWN weight tile's bank; a K-sharded
+    weight psums the partial deltas over the contracted axis.
 
     The bank axis is replicated by default; under pod-local rules
-    (DESIGN.md §17) it shards over "pod" and ``variant_idx`` — which the
-    engine writes as GLOBAL slot ids (pod p owns slots [p*S, (p+1)*S)) —
-    is translated to the shard-local slot by subtracting this pod's
-    offset.  The affinity router only ever routes a row to its own pod's
-    slots, so the clamp is a memory-safety bound, not a semantic path."""
+    (DESIGN.md §17) it shards over "pod" and ``vidx`` — which the engine
+    writes as GLOBAL slot ids (pod p owns slots [p*S, (p+1)*S)) — is
+    translated to the shard-local slot by subtracting this pod's offset.
+    The affinity router only ever routes a row to its own pod's slots,
+    so the clamp is a memory-safety bound, not a semantic path."""
     mesh, rules = st
-    wq, ws = _unwrap_quant(w_base)
-    *lead, k = x.shape
-    n = wq.shape[0]
-    nb = packed.shape[0]
-    x2 = x.reshape(-1, k)
-    m = x2.shape[0]
+    nb, n, _ = packed.shape
+    m, k = x2.shape
     plan = plan_matmul(mesh, rules, waxes, m, n, k)
     if plan is None:
         return None
     mp, op, ip = plan.m_part, plan.o_part, plan.i_part
     bp = _bank_part(mesh, rules, nb, plan)
     lnb = nb // _size(mesh, bp)         # shard-local bank slots
-    import repro.kernels.ops as _O
-    vidx2 = _O.flatten_vidx(variant_idx, tuple(lead)).reshape(m, 1)
 
-    def shard_fn(x2, vi, pk, vr, vc, wb, *ws_op):
-        from repro.kernels.bitlinear import bitlinear_axes_banked_p
+    def shard_fn(xl, vi, pk, vr, vc):
         import repro.kernels.ops as O
-        lm, lk = x2.shape
-        ln = wb.shape[0]
         if bp is not None:
             off = _axes_linear_index(_names(bp)) * lnb
             vi = jnp.clip(vi - off, 0, lnb - 1)
-        y = bitlinear_axes_banked_p(
-            x2, vi, pk, vr.reshape(lnb, ln, 1), vc.reshape(lnb, 1, lk), wb,
-            block_m=O._pick_block(lm, O._TILE_BANKED_M),
-            block_n=O._pick_block(ln, O._TILE_BANKED_N),
-            block_k=O._pick_block(lk, O._TILE_BANKED_K, multiple=PACK),
-            interpret=O._interpret(),
-            w_scale=ws_op[0].reshape(ln, 1) if ws_op else None)
+        y = O.sign_delta_local(xl, vi, pk, vr, vc)
         if plan.psum_axes:
             y = jax.lax.psum(y, plan.psum_axes)
         return y
 
-    pk = packed.reshape(nb, n, k // PACK)
-    vr = v_row.reshape(nb, n)
-    vc = v_col.reshape(nb, k)
-    in_specs = (P(mp, ip), P(mp, None), P(bp, op, ip), P(bp, op),
-                P(bp, ip), P(op, ip))
-    operands = (x2, vidx2, pk, vr, vc, wq)
-    if ws is not None:
-        in_specs += (P(op),)
-        operands += (ws.reshape(n),)
+    operands = (x2, vidx, packed, v_row, v_col)
     fn = _cached_jit(
-        ("banked", mesh, plan, bp, _avals(*operands)),
+        ("sign", mesh, plan, bp, _avals(*operands)),
         lambda: shard_map(
             shard_fn, mesh=mesh,
-            in_specs=in_specs,
-            out_specs=P(mp, op),
-            check_rep=False))
-    y = fn(*operands)
-    return y.astype(x.dtype).reshape(*lead, n)
+            in_specs=(P(mp, ip), P(mp), P(bp, op, ip), P(bp, op),
+                      P(bp, ip)),
+            out_specs=P(mp, op),    # op is None whenever ip carried model
+            check_vma=False))
+    return fn(*operands)
 
 
-def bitlinear_axes_stacked(st, xe: jax.Array, entry, w,
-                           waxes) -> Optional[jax.Array]:
-    """shard_map'd per-expert fused GEMMs: xe (E, M, D) · entry leaves
-    (E, F, D/8)/(E, F)/(E, D) · w (E, F, D) -> (E, M, F).
+def sign_delta_stacked(st, xe: jax.Array, entry,
+                       waxes) -> Optional[jax.Array]:
+    """shard_map'd per-expert sign deltas: xe (E, M, D) · entry leaves
+    (E, F, D/8)/(E, F)/(E, D) -> (E, M, F) fp32.
 
     The MoE expert stacks shard their EXPERT dim over the model axis, so
-    the per-shard body vmaps the 2-D kernel over the local experts —
+    the per-shard body vmaps the kernel over the local experts —
     shard_map(vmap(kernel)), the composition that works, instead of
-    vmap(shard_map(kernel)), which does not.  Falls back through the same
-    plan contract when experts don't divide (then ffn/embed may carry the
-    axis and the contraction psums).  ``w`` may be a QuantWeight with an
-    (E, F) scale riding the expert/ffn axes."""
+    vmap(shard_map(kernel)), which does not.  When experts don't divide,
+    ffn/embed may carry the axis and the contraction psums."""
     mesh, rules = st
-    wq, ws = _unwrap_quant(w)
     if waxes is None or len(waxes) != 3:
         return None
     from repro.distributed.sharding import resolve_spec
-    e, m, d = xe.shape
-    f = wq.shape[1]
+    e, _, d = xe.shape
+    f = entry.packed.shape[1]
     ep, fp, dp = resolve_spec((e, f, d), tuple(waxes), rules, mesh)
     if dp is not None and (d // _size(mesh, dp)) % PACK:
         return None
-    if ep is None and fp is None and dp is None:
-        return None
     psum_axes = _names(dp)
 
-    def shard_fn(xl, pk, vr, vc, wb, *ws_op):
-        from repro.kernels.bitlinear import bitlinear_axes_p
+    def shard_fn(xl, pk, vr, vc):
         import repro.kernels.ops as O
-        _, lm, ld = xl.shape
-        lf = wb.shape[1]
-        bm = O._pick_block(lm, O._TILE_M)
-        bn = O._pick_block(lf, O._TILE_N)
-        bk = O._pick_block(ld, O._TILE_K, multiple=PACK)
 
-        def one(x2, p2, r2, c2, w2, *s2):
-            return bitlinear_axes_p(
-                x2, p2, r2.reshape(lf, 1), c2.reshape(1, ld), w2,
-                block_m=bm, block_n=bn, block_k=bk,
-                interpret=O._interpret(),
-                w_scale=s2[0].reshape(lf, 1) if s2 else None)
+        def one(x2, p2, r2, c2):
+            return O.sign_delta_local(
+                x2, jnp.zeros((x2.shape[0],), jnp.int32), p2[None],
+                r2[None], c2[None])
 
-        y = jax.vmap(one)(xl, pk, vr, vc, wb, *ws_op)
+        y = jax.vmap(one)(xl, pk, vr, vc)
         if psum_axes:
             y = jax.lax.psum(y, psum_axes)
         return y
 
-    in_specs = (P(ep, None, dp), P(ep, fp, dp), P(ep, fp), P(ep, dp),
-                P(ep, fp, dp))
-    operands = (xe, entry.packed, entry.v_row, entry.v_col, wq)
-    if ws is not None:
-        in_specs += (P(ep, fp),)
-        operands += (ws,)
+    operands = (xe, entry.packed, entry.v_row, entry.v_col)
     fn = _cached_jit(
         ("stacked", mesh, (ep, fp, dp), _avals(*operands)),
         lambda: shard_map(
             shard_fn, mesh=mesh,
-            in_specs=in_specs,
+            in_specs=(P(ep, None, dp), P(ep, fp, dp), P(ep, fp), P(ep, dp)),
             out_specs=P(ep, None, fp),
-            check_rep=False))
-    y = fn(*operands)
-    return y.astype(xe.dtype)
+            check_vma=False))
+    return fn(*operands)
 
 
 def unpack_apply(st, packed: jax.Array, v: jax.Array, w_base,
@@ -495,6 +398,7 @@ def unpack_apply(st, packed: jax.Array, v: jax.Array, w_base,
     """shard_map'd Ŵ = v ⊙ unpack(B) + W_b: pure per-tile reconstruction,
     no contraction — every shard rebuilds exactly its own weight tile.
     ``w_base`` may be a QuantWeight (int8 base, per-tile dequant)."""
+    from repro.kernels.ops import _unwrap_quant, _v2d
     mesh, rules = st
     wq, ws = _unwrap_quant(w_base)
     n, k = wq.shape
@@ -507,16 +411,9 @@ def unpack_apply(st, packed: jax.Array, v: jax.Array, w_base,
 
     def shard_fn(pk, v2, wb, *ws_op):
         import repro.kernels.ops as O
-        from repro.kernels.unpack_apply import unpack_apply_p
-        ln, lk = wb.shape
-        return unpack_apply_p(
-            pk, v2, wb,
-            block_m=O._pick_block(ln, O._TILE_M),
-            block_n=O._pick_block(lk, O._TILE_N, multiple=PACK),
-            out_dtype=out_dtype, interpret=O._interpret(),
-            w_scale=ws_op[0].reshape(ln, 1) if ws_op else None)
+        return O.unpack_apply_local(pk, v2, wb, ws_op[0] if ws_op else None,
+                                    out_dtype)
 
-    from repro.kernels.ops import _v2d
     v2 = _v2d(v, mode, n, k)
     in_specs = (P(op, ip), v_spec, P(op, ip))
     operands = (packed, v2, wq)
@@ -530,5 +427,5 @@ def unpack_apply(st, packed: jax.Array, v: jax.Array, w_base,
             shard_fn, mesh=mesh,
             in_specs=in_specs,
             out_specs=P(op, ip),
-            check_rep=False))
+            check_vma=False))
     return fn(*operands)

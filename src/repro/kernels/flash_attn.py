@@ -74,7 +74,7 @@ def flash_attention_fwd_p(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           group: int, causal: bool = True,
                           q_offset: int = 0, kv_offset: int = 0,
                           block_q: int = 128, block_k: int = 128,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool) -> jax.Array:
     """q (BH, S, hd); k/v (BH//group, T, hd) -> o (BH, S, hd)."""
     bh, s, hd = q.shape
     _, t, _ = k.shape

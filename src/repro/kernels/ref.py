@@ -70,6 +70,8 @@ def bitlinear_axes_banked_ref(x: jax.Array, variant_idx: jax.Array,
     v = (v_row.astype(jnp.float32)[:, :, None]
          + v_col.astype(jnp.float32)[:, None, :])
     w_hat = v * signs + _deq(w_base, w_scale)[None]          # (V, N, K)
-    w_sel = jnp.take(w_hat, variant_idx, axis=0)             # (M, N, K)
-    y = jnp.einsum("mnk,mk->mn", w_sel, x.astype(jnp.float32))
+    # every row against every slot, then each row keeps its own slot's
+    # result: a (V, M, N) product instead of an (M, N, K) per-row gather
+    y_all = jnp.einsum("vnk,mk->vmn", w_hat, x.astype(jnp.float32))
+    y = jnp.take_along_axis(y_all, variant_idx[None, :, None], axis=0)[0]
     return y.astype(x.dtype)

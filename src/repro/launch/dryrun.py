@@ -1,9 +1,11 @@
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 MUST be the process entry point (python -m repro.launch.dryrun ...): the
-first two lines force 512 host platform devices BEFORE any jax import so
-``jax.make_mesh`` can build the production meshes.  Never set this flag
-globally — smoke tests and benchmarks must see 1 device.
+first lines pin JAX to the CPU and force 512 host platform devices BEFORE
+any jax import so ``jax.make_mesh`` can build the production meshes.  This
+is a CPU analysis of a modelled TPU v5e: it never touches an accelerator,
+and the per-cell child processes inherit the same CPU pin.  Never set
+these flags globally — smoke tests and benchmarks must see 1 device.
 
 Per cell this:
   1. builds the mesh ((16,16) data×model, or (2,16,16) pod×data×model),
@@ -23,8 +25,12 @@ restart (the dry-run is itself fault-tolerant / resumable).
 """
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
+
+# the chip the roofline models (hlo_analysis.PEAKS key)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 import argparse
 import json
@@ -277,7 +283,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         model_flops = 2 * n_matmul * shape.global_batch
     terms = H.roofline_terms(summary["flops"], summary["bytes_accessed"],
                              summary["collectives"]["total_wire_bytes"],
-                             model_flops_per_device=model_flops / n_chips)
+                             model_flops_per_device=model_flops / n_chips,
+                             device_kind=TARGET_DEVICE_KIND)
     if cc_how is not None:
         meta["cache"] = cc_how
     return {**meta, "status": "ok", "lower_s": round(t_lower, 2),

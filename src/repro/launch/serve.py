@@ -1,9 +1,18 @@
 """Serving launcher: versioned multi-tenant deployment over synthetic
 delta variants, driven through the serving/api.Deployment control plane.
 
+The base weights and the variant artifacts are random, drawn from
+fixed seeds by ``launch/build.py`` in their serving form (bf16 or int8
+base, packed sign planes + fp16 vectors per variant), so the launcher
+runs at published widths as well as ``--reduced``::
+
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --reduced \
         --variants 3 --requests 12 --mode fused --scheduler continuous \
         --updates 1
+
+    # deepseek-7b at full width on one 16 GB chip (int8 base)
+    PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b \
+        --base-dtype int8 --mode fused --scheduler continuous --variants 2
 
 --mode fused keeps variants resident as packed delta overlays (on-the-fly
 fused GEMMs, ~1/16 the HBM per variant); --mode dense materialises full
@@ -48,11 +57,11 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--mode", choices=("dense", "fused"), default="dense")
     ap.add_argument("--base-dtype", choices=("fp", "int8"), default="fp",
-                    help="resident base-weight dtype: int8 quantizes every "
-                         "shadowed target (symmetric per-channel, "
-                         "core/quantize.py) and the fused GEMMs dequantize "
-                         "per tile — ~0.5x resident base HBM (DESIGN.md "
-                         "§16)")
+                    help="resident base-weight dtype: fp is bf16; int8 "
+                         "stores every shadowed target symmetric "
+                         "per-channel (core/quantize.py) — ~0.5x resident "
+                         "base HBM (DESIGN.md §16); deepseek-7b at full "
+                         "width fits one 16 GB chip only as int8")
     ap.add_argument("--scheduler", choices=("group", "continuous"),
                     default="group")
     ap.add_argument("--max-resident", type=int, default=0,
@@ -118,13 +127,14 @@ def main():
                  "(staged overlays commit into the overlay bank between "
                  "decode steps)")
 
-    import jax
     import numpy as np
     from repro.configs import get_config
-    from repro.core import calibration as C
+    from repro.core.compile_cache import use_jax_cache
+    from repro.launch import build as B
     from repro.models import build_model
-    from repro.models.param import split
     from repro.serving import Deployment
+
+    use_jax_cache()
 
     mesh = None
     if args.mesh:
@@ -152,23 +162,27 @@ def main():
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg)
-    base, param_axes = split(model.init(jax.random.PRNGKey(0)))
-
-    def fine_tune(seed: int, scale: float = 0.005):
-        key = jax.random.PRNGKey(seed)
-        leaves, treedef = jax.tree.flatten(base)
-        keys = jax.random.split(key, len(leaves))
-        return jax.tree.unflatten(treedef, [
-            l + scale * jax.random.normal(k, l.shape, l.dtype)
-            if l.ndim >= 2 else l for l, k in zip(leaves, keys)])
+    # the base is built in its resident dtype and the variants are drawn
+    # as packed artifacts (launch/build.py): nothing full-precision or
+    # dense per variant is ever materialised, so published widths fit
+    bank_size = args.variants + 2
+    plan = B.memory_plan(model, base_dtype=args.base_dtype,
+                         bank_size=bank_size, kv_tokens=args.batch * 64)
+    print(f"memory plan: base {plan['base'] / 1e9:.3f} GB, bank slot "
+          f"{plan['slot'] / 1e9:.3f} GB x {bank_size}, KV "
+          f"{plan['kv_per_token'] / 1e6:.4f} MB/token; total "
+          f"{plan['total'] / 1e9:.3f} GB")
+    base, param_axes, param_shardings = B.init_base(
+        model, 0, base_dtype=args.base_dtype, mesh=mesh)
 
     max_resident = args.max_resident or (8 if args.mode == "fused" else 2)
     dep = Deployment(model, base, root_dir=args.store_dir,
                      mode=args.mode, scheduler=args.scheduler,
                      batch_size=args.batch, prompt_len=16, max_len=64,
                      max_resident=max_resident,
-                     bank_size=args.variants + 2,
+                     bank_size=bank_size,
                      mesh=mesh, param_axes=param_axes if mesh else None,
+                     param_shardings=param_shardings,
                      kernel_dispatch=args.kernel_dispatch,
                      async_admission=args.async_admission,
                      speculative=args.speculative, draft_k=args.draft_k,
@@ -182,10 +196,12 @@ def main():
         print(f"int8 base: {qs['targets']} targets, "
               f"{qs['fp_bytes']} -> {qs['int8_bytes']} bytes "
               f"(ratio {qs['ratio']:.3f})")
+    del base                    # the deployment holds the resident base
+    flat_shapes, _, _ = B.base_struct(model)
     tunes = {}
     for i in range(args.variants):
-        tunes[f"v{i}"] = fine_tune(100 + i)
-        dep.publish(f"v{i}", C.compress(base, tunes[f"v{i}"]))
+        tunes[f"v{i}"] = B.random_variant(flat_shapes, 100 + i)
+        dep.publish(f"v{i}", tunes[f"v{i}"])
 
     rng = np.random.default_rng(0)
     names = dep.variants()
@@ -196,12 +212,9 @@ def main():
     dep.drain()
 
     for u in range(args.updates):
-        # continue v0's fine-tune a little and ship it as a patch
-        ft = jax.tree.map(
-            lambda l, b: l + 0.2 * (l - b) if l.ndim >= 2 else l,
-            tunes["v0"], base)
-        tunes["v0"] = ft
-        v = dep.update("v0", C.compress(base, ft))
+        # a small residual on v0, shipped as a patch
+        tunes["v0"] = B.perturb_variant(tunes["v0"], 200 + u)
+        v = dep.update("v0", tunes["v0"])
         print(f"update {u}: v0 -> version {v}")
         for _ in range(args.batch):
             dep.submit(rng.integers(1, cfg.vocab_size, size=8),

@@ -40,11 +40,9 @@ def linear(x: jax.Array, w: jax.Array, ov=None, vidx=None,
     contraction — so the dense fp base is never materialised; overlay
     paths hand the QuantWeight to the kernels, which dequantize per
     tile (DESIGN.md §16)."""
-    if ov is None:
-        if getattr(w, "__quant_leaf__", False):
-            return (x @ w.q.T.astype(x.dtype)) * w.scale.astype(x.dtype)
-        return x @ w.T.astype(x.dtype)
     from repro.kernels import ops as K
+    if ov is None:
+        return K.base_matmul(x, w)
     if vidx is None:
         return K.bitlinear_axes(x, ov.packed, ov.v_row, ov.v_col, w,
                                 waxes=waxes)

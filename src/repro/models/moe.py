@@ -66,29 +66,31 @@ def _expert_mm(xe: jax.Array, w: jax.Array, ent,
                waxes=("experts", "ffn", "embed")) -> jax.Array:
     """Per-expert matmul: xe (E, M, D) · w (E, F, D) -> (E, M, F).
 
-    With a delta-overlay entry (stacked over the expert dim) each expert's
-    GEMM runs the fused on-the-fly delta kernel against its base weight.
-    Inside an active mesh the whole stack lowers as ONE shard_map over the
-    expert-sharded axis — shard_map(vmap(kernel)), each device running the
-    fused kernels for its own experts — because the plain formulation here
-    (vmap over a shard_map'd kernel) is not a supported composition; the
-    dispatcher declines (None) when the stack can't partition and the
-    global vmap path below runs under GSPMD exactly as before."""
-    if ent is None:
-        if getattr(w, "__quant_leaf__", False):
-            # int8 base: per-output-channel scales factor out of the
-            # contraction exactly (the scaled dim survives to the output)
-            return (jnp.einsum("emd,efd->emf", xe, w.q.astype(xe.dtype))
-                    * w.scale.astype(xe.dtype)[:, None, :])
-        return jnp.einsum("emd,efd->emf", xe, w.astype(xe.dtype))
+    The base term is the shared ``ops.base_matmul`` per expert (an int8
+    base factors its per-output-channel scales out exactly); with a
+    delta-overlay entry (stacked over the expert dim) the sign-plane delta
+    of each expert is added.  Inside an active mesh the delta lowers as
+    ONE shard_map over the expert-sharded axis — shard_map(vmap(kernel)),
+    each device running the kernels for its own experts — because vmap
+    over a shard_map'd kernel is not a supported composition; a stack the
+    dispatcher declines runs the global vmapped kernel and is counted."""
     from repro.kernels import dispatch as D
+    from repro.kernels import ops as K
+    base = jax.vmap(K.base_matmul)(xe, w)
+    if ent is None:
+        return base
     st = D.state()
+    y = None
     if st is not None:
-        y = D.bitlinear_axes_stacked(st, xe, ent, w, waxes)
-        if y is not None:
-            return y
-    with D.no_dispatch():
-        return jax.vmap(lambda x_, e_, w_: linear(x_, w_, e_))(xe, ent, w)
+        y = D.sign_delta_stacked(st, xe, ent, waxes)
+        if y is None:
+            D.note_decline()
+    if y is None:
+        zeros = jnp.zeros(xe.shape[1:2], jnp.int32)
+        y = jax.vmap(lambda x_, p_, r_, c_: K.sign_delta_local(
+            x_, zeros, p_[None], r_[None], c_[None]))(
+                xe, ent.packed, ent.v_row, ent.v_col)
+    return base + y.astype(base.dtype)
 
 
 def moe_apply(p: dict, x: jax.Array, cfg, ov=None, vidx=None
@@ -145,19 +147,19 @@ def moe_apply(p: dict, x: jax.Array, cfg, ov=None, vidx=None
         xg[:, None, :, :], c_idx[..., None], axis=2)            # (G,E,C,D)
     xd = logical_constraint(xd, "act_groups", "act_experts", None, None)
 
-    # grouped expert GEMMs (gated SwiGLU); with an overlay the per-expert
-    # matmuls run expert-major (E, G·C, ·) so the fused delta kernel sees
-    # one (M, K) GEMM per expert stack entry
+    # grouped expert GEMMs (gated SwiGLU), expert-major (E, G·C, ·): one
+    # (M, K) GEMM per expert stack entry, with or without an overlay, so a
+    # base row computes the same matmuls on every path
+    xe = xd.transpose(1, 0, 2, 3).reshape(e, g * cap, d)
     has_delta = ov is not None and any(oget(ov, k_) is not None
                                        for k_ in ("w_gate", "w_up", "w_down"))
     if has_delta and vidx_gn is not None:
         # mixed-variant banked overlay: masked per-variant application —
         # banking the per-row gather inside the grouped (E, M, ·) GEMMs is
         # awkward (rows are dispatch slots, not batch lanes), so run the
-        # existing per-variant fused pass once per bank slot with
-        # non-matching rows zeroed and select (slot 0 = base weights)
+        # per-variant pass once per bank slot with non-matching rows
+        # zeroed and select (slot 0 = base weights)
         from repro.models.delta_overlay import entry_slot
-        xe = xd.transpose(1, 0, 2, 3).reshape(e, g * cap, d)
         vd = jnp.take_along_axis(vidx_gn[:, None, :], c_idx, axis=2)  # (G,E,C)
         vidx_e = vd.transpose(1, 0, 2).reshape(e, g * cap)
         ents = {k_: oget(ov, k_) for k_ in ("w_gate", "w_up", "w_down")}
@@ -173,26 +175,12 @@ def moe_apply(p: dict, x: jax.Array, cfg, ov=None, vidx=None
             yv = _expert_mm(hv, p["w_down"], sl["w_down"],
                             waxes=("experts", "embed", "ffn"))
             ye = jnp.where(mask, yv, ye)
-        yd = ye.reshape(e, g, cap, d).transpose(1, 0, 2, 3)
-    elif has_delta:
-        xe = xd.transpose(1, 0, 2, 3).reshape(e, g * cap, d)
+    else:
         he = (jax.nn.silu(_expert_mm(xe, p["w_gate"], oget(ov, "w_gate")))
               * _expert_mm(xe, p["w_up"], oget(ov, "w_up")))
         ye = _expert_mm(he, p["w_down"], oget(ov, "w_down"),
                         waxes=("experts", "embed", "ffn"))
-        yd = ye.reshape(e, g, cap, d).transpose(1, 0, 2, 3)
-    else:
-        def emm(eq, xop, w):
-            # possibly-quantized expert stack: scale (E, F_out) broadcasts
-            # onto the (G, E, C, F_out) output — exact factoring, no dense
-            # dequant (DESIGN.md §16)
-            if getattr(w, "__quant_leaf__", False):
-                return (jnp.einsum(eq, xop, w.q.astype(x.dtype))
-                        * w.scale.astype(x.dtype)[None, :, None, :])
-            return jnp.einsum(eq, xop, w.astype(x.dtype))
-        h = jax.nn.silu(emm("gecd,efd->gecf", xd, p["w_gate"])) * \
-            emm("gecd,efd->gecf", xd, p["w_up"])
-        yd = emm("gecf,edf->gecd", h, p["w_down"])
+    yd = ye.reshape(e, g, cap, d).transpose(1, 0, 2, 3)
     yd = yd * c_val[..., None].astype(x.dtype)                  # combine weight
     # mask out capacity slots that hold zero-score (unrouted) tokens
     yd = jnp.where((c_val > 0)[..., None], yd, 0)

@@ -28,15 +28,37 @@ PROMPT = np.arange(1, 7)
 
 @pytest.fixture(autouse=True)
 def _no_xla_cache_leak():
-    """CompileCache points jax's own persistent cache at its directory
-    (the fallback layer); tmp dirs die with the test, so unhook the
-    global config afterwards."""
+    """use_jax_cache points jax's own persistent cache at a directory;
+    unhook the global config after each test."""
+    prev = jax.config.jax_compilation_cache_dir
     yield
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+# ---------------------------------------------------------------------------
+# JAX's persistent cache placement (entry points call use_jax_cache)
+# ---------------------------------------------------------------------------
+
+def test_jax_cache_follows_env_var(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache and
+    no other is configured."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert CC.use_jax_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_jax_cache_defaults_to_checkout(monkeypatch):
+    """Without the variable, the cache is the fixed <checkout>/.jax_cache
+    (never a temporary, per-process or timestamped path)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    import pathlib
+    want = pathlib.Path(CC.__file__).resolve().parents[3] / ".jax_cache"
+    assert (want.parent / "pyproject.toml").exists() or \
+        (want.parent / "ROADMAP.md").exists()
+    assert CC.use_jax_cache() == str(want)
+    assert jax.config.jax_compilation_cache_dir == str(want)
+    assert CC.use_jax_cache() == str(want)      # stable across calls
 
 
 @pytest.fixture(scope="module")
@@ -61,7 +83,7 @@ def _compiled_double():
 
 
 def test_roundtrip_and_counters(tmp_path):
-    cc = CC.CompileCache(tmp_path, xla_fallback=False)
+    cc = CC.CompileCache(tmp_path)
     parts = ("unit", "double", CC.aval_fp(jnp.ones((4,), jnp.float32)))
     assert cc.get(parts) is None
     assert cc.stats["misses"] == 1
@@ -74,7 +96,7 @@ def test_roundtrip_and_counters(tmp_path):
 
 
 def test_corrupt_and_truncated_entries_miss(tmp_path):
-    cc = CC.CompileCache(tmp_path, xla_fallback=False)
+    cc = CC.CompileCache(tmp_path)
     parts = ("unit", "corrupt")
     cc.put(parts, _compiled_double())
     entry = cc._entry(cc.key(*parts))
@@ -95,7 +117,7 @@ def test_corrupt_and_truncated_entries_miss(tmp_path):
 
 def test_env_fingerprint_mismatch_misses(tmp_path):
     import pickle
-    cc = CC.CompileCache(tmp_path, xla_fallback=False)
+    cc = CC.CompileCache(tmp_path)
     parts = ("unit", "envdrift")
     cc.put(parts, _compiled_double())
     entry = cc._entry(cc.key(*parts))
@@ -109,7 +131,7 @@ def test_env_fingerprint_mismatch_misses(tmp_path):
 
 
 def test_mesh_and_code_fingerprints_separate_keys(tmp_path):
-    cc = CC.CompileCache(tmp_path, xla_fallback=False)
+    cc = CC.CompileCache(tmp_path)
     dev = np.array(jax.devices()[:1])
     mesh_a = jax.sharding.Mesh(dev.reshape(1), ("data",))
     mesh_b = jax.sharding.Mesh(dev.reshape(1, 1), ("data", "model"))
@@ -123,7 +145,7 @@ def test_mesh_and_code_fingerprints_separate_keys(tmp_path):
 
 
 def test_cached_callable_static_kwargs_and_persistence(tmp_path):
-    cc = CC.CompileCache(tmp_path, xla_fallback=False)
+    cc = CC.CompileCache(tmp_path)
     fn = jax.jit(lambda x, n: x * n, static_argnames=("n",))
     x = jnp.ones((3,), jnp.float32)
 
