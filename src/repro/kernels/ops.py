@@ -260,12 +260,16 @@ def base_matmul(x: jax.Array, w) -> jax.Array:
     The optimization barrier pins the rounded result: without it XLA may
     fuse the matmul into different consumers in the plain and the banked
     step programs and keep excess precision in one of them, and a base
-    lane of a mixed batch would then drift from plain serving."""
-    if getattr(w, "__quant_leaf__", False):
-        y = (x @ w.q.T.astype(x.dtype)) * w.scale.astype(x.dtype)
-    else:
-        y = x @ w.T.astype(x.dtype)
-    return jax.lax.optimization_barrier(y)
+    lane of a mixed batch would then drift from plain serving.
+
+    Its ops carry the name scope ``base_gemm`` (op metadata only: the
+    program is the same), which the benchmark's trace reduction reads."""
+    with jax.named_scope("base_gemm"):
+        if getattr(w, "__quant_leaf__", False):
+            y = (x @ w.q.T.astype(x.dtype)) * w.scale.astype(x.dtype)
+        else:
+            y = x @ w.T.astype(x.dtype)
+        return jax.lax.optimization_barrier(y)
 
 
 def sign_delta_local(x2: jax.Array, vidx: jax.Array, packed: jax.Array,

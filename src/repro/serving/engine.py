@@ -48,6 +48,16 @@ kernels on each device's own weight/overlay tile (kernels/dispatch.py)
 instead of trusting GSPMD to partition the opaque kernel call;
 ``kernel_dispatch="gspmd"`` pins the PR-4 global-kernel lowering for A/B
 parity and latency comparisons.
+
+Tracing: each round of the continuous loop is a ``serve.step`` profiler
+span (``StepTraceAnnotation``) holding one span per part of it —
+``serve.admit``, ``serve.prefill`` (``.wait``), ``serve.merge``,
+``serve.emit``, ``serve.decode`` (``.wait``), ``serve.hook`` — and a step
+executable resolved inside it shows as ``serve.compile``.  They record
+nothing without a profiler session.  ``metrics["host_seconds"]`` sums each
+round's wall time less its waits on the device, the step hook and
+admission sleeps; ``metrics["prefill_rows"]`` counts the rows admission
+waves admitted (each wave computes all ``batch_size`` rows).
 """
 from __future__ import annotations
 
@@ -60,6 +70,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import compile_cache as CC
@@ -298,10 +309,11 @@ class ServingEngine:
         # arrival order (deterministic sliding window, no RNG)
         self._ttft_cap = 1024
         self._ttft_samples: list = []
-        self.metrics = {"batches": 0, "tokens_generated": 0,
-                        "prefills": 0, "failed": 0, "admitted": 0,
-                        "retired": 0, "decode_steps": 0,
+        self.metrics = {"tokens_generated": 0,
+                        "prefills": 0, "prefill_rows": 0, "failed": 0,
+                        "admitted": 0, "retired": 0, "decode_steps": 0,
                         "prefill_seconds": 0.0, "decode_seconds": 0.0,
+                        "host_seconds": 0.0,
                         "async_admits": 0,
                         "step_compiles": 0, "step_cache_hits": 0,
                         "step_compile_seconds": 0.0,
@@ -326,6 +338,10 @@ class ServingEngine:
         # stall-ceiling evidence
         self.record_step_times = False
         self.step_times: list = []
+        # seconds the current continuous round spent off the engine's host
+        # code: blocked on the device, in the step hook, asleep on
+        # admission (reset by each round, _round)
+        self._off_host = 0.0
 
     # -- sharded step dispatch -----------------------------------------------
     def _arg_sharding(self, role: str, arg):
@@ -414,23 +430,31 @@ class ServingEngine:
         exe = self._exe.get(key)
         if exe is not None:
             return exe
-        cc = self.compile_cache
-        if cc is not None:
-            exe = cc.get(self._persist_parts(kind, args))
-            if exe is not None:
-                self.metrics["step_cache_hits"] += 1
-                self._exe[key] = exe
-                return exe
-        jitted = self._stage_jit(kind, args)
-        t0 = time.perf_counter()
-        with self._trace_ctx():
-            exe = jitted.lower(*args).compile()
-        self.metrics["step_compiles"] += 1
-        self.metrics["step_compile_seconds"] += time.perf_counter() - t0
-        if cc is not None:
-            cc.put(cc.key(*self._persist_parts(kind, args)), exe)
+        with TraceAnnotation("serve.compile", kind=kind):
+            cc = self.compile_cache
+            if cc is not None:
+                exe = cc.get(self._persist_parts(kind, args))
+                if exe is not None:
+                    self.metrics["step_cache_hits"] += 1
+                    self._exe[key] = exe
+                    return exe
+            jitted = self._stage_jit(kind, args)
+            t0 = time.perf_counter()
+            with self._trace_ctx():
+                exe = jitted.lower(*args).compile()
+            self.metrics["step_compiles"] += 1
+            self.metrics["step_compile_seconds"] += time.perf_counter() - t0
+            if cc is not None:
+                cc.put(cc.key(*self._persist_parts(kind, args)), exe)
         self._exe[key] = exe
         return exe
+
+    def step_hlo(self) -> dict:
+        """Optimized HLO text of each step program resolved so far, by
+        kind.  Its instructions carry the op metadata that a chip's
+        profiler trace lacks (the name stack, with the ``base_gemm`` and
+        ``attention`` scopes), under the names the trace gives its ops."""
+        return {kind: exe.as_text() for (kind, _), exe in self._exe.items()}
 
     def _call(self, kind: str, *args):
         """Run one step executable (resolving it on first use)."""
@@ -849,7 +873,6 @@ class ServingEngine:
         for r in group:
             r.status = "done"
             self._done[r.rid] = r
-        self.metrics["batches"] += 1
 
     # -- continuous slot scheduler (mixed-variant batches) -------------------
     def _merge_admitted(self, old, fresh, admit_rows: list):
@@ -932,73 +955,77 @@ class ServingEngine:
         pin it for the request's lifetime.  Artifact failures re-queue up
         to max_retries then fail; a fully-pinned bank re-queues the head
         and waits for retirements."""
-        newly: list = []
-        skipped: list = []
-        free = [i for i in range(self.batch_size) if self._slots[i] is None]
-        while free and self._queue:
-            r = self._queue.popleft()
-            pod = self._route_pod(r, free)
-            if not any(self._lane_pod(i) == pod for i in free):
-                # sticky pod's lanes all busy: hold the request until a
-                # retirement frees one (re-routing would thrash per-pod
-                # admission tickets and bank slots)
-                skipped.append(r)
-                continue
-            if self.admission is not None and r.variant != "__base__":
-                # async path: never load on the serving thread — consult
-                # the pipeline (auto-prefetching unseen variants) and skip
-                # the request while its version is still ingesting
+        with TraceAnnotation("serve.admit") as span:
+            newly: list = []
+            skipped: list = []
+            free = [i for i in range(self.batch_size)
+                    if self._slots[i] is None]
+            while free and self._queue:
+                r = self._queue.popleft()
+                pod = self._route_pod(r, free)
+                if not any(self._lane_pod(i) == pod for i in free):
+                    # sticky pod's lanes all busy: hold the request until a
+                    # retirement frees one (re-routing would thrash per-pod
+                    # admission tickets and bank slots)
+                    skipped.append(r)
+                    continue
+                if self.admission is not None and r.variant != "__base__":
+                    # async path: never load on the serving thread — consult
+                    # the pipeline (auto-prefetching unseen variants) and skip
+                    # the request while its version is still ingesting
+                    try:
+                        state = self.admission.poll(r.variant, pod=pod)
+                    except Exception as e:   # ingest failed: same retry budget
+                        r.retries += 1       # as the sync artifact-load path
+                        if r.retries > self.max_retries:
+                            r.status, r.error = "failed", str(e)
+                            self._done[r.rid] = r
+                            self.metrics["failed"] += 1
+                        else:
+                            r.status = "queued"
+                            self._queue.append(r)
+                        continue
+                    if state != "admitted":
+                        r.status = "admitting"
+                        skipped.append(r)
+                        continue
                 try:
-                    state = self.admission.poll(r.variant, pod=pod)
-                except Exception as e:   # ingest failed: same retry budget
-                    r.retries += 1       # as the sync artifact-load path
+                    # admission-time resolution: a queued request follows
+                    # the serving pointer at THIS moment — a version
+                    # published (or rolled back) while it waited is what it
+                    # serves.  The acquire pins the resolved VERSION KEY, so
+                    # a later swap cannot evict the bank slot this lane
+                    # decodes from.
+                    vslot, vkey = self.registry.bank_acquire(r.variant, pod)
+                except RuntimeError:
+                    # every bank slot pinned by in-flight requests: transient
+                    # capacity pressure — retry after retirements free pins
+                    self._queue.appendleft(r)
+                    break
+                except Exception as e:
+                    r.retries += 1
                     if r.retries > self.max_retries:
                         r.status, r.error = "failed", str(e)
                         self._done[r.rid] = r
                         self.metrics["failed"] += 1
                     else:
-                        r.status = "queued"
                         self._queue.append(r)
                     continue
-                if state != "admitted":
-                    r.status = "admitting"
-                    skipped.append(r)
-                    continue
-            try:
-                # admission-time resolution: a queued request follows the
-                # serving pointer at THIS moment — a version published (or
-                # rolled back) while it waited is what it serves.  The
-                # acquire pins the resolved VERSION KEY, so a later swap
-                # cannot evict the bank slot this lane decodes from.
-                vslot, vkey = self.registry.bank_acquire(r.variant, pod)
-            except RuntimeError:
-                # every bank slot pinned by in-flight requests: transient
-                # capacity pressure — retry after retirements free pins
-                self._queue.appendleft(r)
-                break
-            except Exception as e:
-                r.retries += 1
-                if r.retries > self.max_retries:
-                    r.status, r.error = "failed", str(e)
-                    self._done[r.rid] = r
-                    self.metrics["failed"] += 1
-                else:
-                    self._queue.append(r)
-                continue
-            i = next(j for j in free if self._lane_pod(j) == pod)
-            free.remove(i)
-            r.served_version = self.registry.current_version(r.variant)
-            self._slots[i] = _Slot(request=r, variant_slot=vslot,
-                                   remaining=r.max_new_tokens, vkey=vkey,
-                                   pod=pod)
-            self._variant_idx[i] = vslot
-            self._variant_idx_dev = None
-            r.status = "running"
-            newly.append(i)
-            self.metrics["admitted"] += 1
-        # skipped (mid-admission) requests return to the FRONT in their
-        # original order: admission order stays FIFO once staging lands
-        self._queue.extendleft(reversed(skipped))
+                i = next(j for j in free if self._lane_pod(j) == pod)
+                free.remove(i)
+                r.served_version = self.registry.current_version(r.variant)
+                self._slots[i] = _Slot(request=r, variant_slot=vslot,
+                                       remaining=r.max_new_tokens, vkey=vkey,
+                                       pod=pod)
+                self._variant_idx[i] = vslot
+                self._variant_idx_dev = None
+                r.status = "running"
+                newly.append(i)
+                self.metrics["admitted"] += 1
+            # skipped (mid-admission) requests return to the FRONT in their
+            # original order: admission order stays FIFO once staging lands
+            self._queue.extendleft(reversed(skipped))
+            span.set_metadata(admitted=len(newly), queued=len(self._queue))
         return newly
 
     def _prefill_admitted(self, newly: list) -> None:
@@ -1006,29 +1033,32 @@ class ServingEngine:
         prefill per admission wave; only the newly admitted rows of the
         resulting cache/logits are merged into the persistent batch."""
         bs = self.batch_size
-        pvidx = self._base_vidx.copy()
-        for i in newly:
-            pvidx[i] = self._slots[i].variant_slot
-        batch = self._prompt_batch(
-            {i: self._slots[i].request for i in newly})
-        bank = self.registry.bank.tree if self.registry.bank else None
-        t0 = time.perf_counter()
-        last_logits, fresh = self._call(
-            "prefill_banked", self.registry.base_params, bank,
-            jnp.asarray(pvidx), batch)
-        jax.block_until_ready(last_logits)
-        self.metrics["prefill_seconds"] += time.perf_counter() - t0
-        self.metrics["prefills"] += 1
-        first_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-        if self._next_tok is None:
-            self._next_tok = first_tok
-            self._cache = fresh
-            return
-        mask = np.zeros(bs, bool)
-        mask[newly] = True
-        self._next_tok = jnp.where(jnp.asarray(mask), first_tok,
-                                   self._next_tok)
-        self._cache = self._merge_admitted(self._cache, fresh, newly)
+        with TraceAnnotation("serve.prefill", rows=len(newly), lanes=bs):
+            pvidx = self._base_vidx.copy()
+            for i in newly:
+                pvidx[i] = self._slots[i].variant_slot
+            batch = self._prompt_batch(
+                {i: self._slots[i].request for i in newly})
+            bank = self.registry.bank.tree if self.registry.bank else None
+            t0 = time.perf_counter()
+            last_logits, fresh = self._call(
+                "prefill_banked", self.registry.base_params, bank,
+                jnp.asarray(pvidx), batch)
+            self._wait(last_logits, "serve.prefill.wait")
+            self.metrics["prefill_seconds"] += time.perf_counter() - t0
+            self.metrics["prefills"] += 1
+            self.metrics["prefill_rows"] += len(newly)
+        with TraceAnnotation("serve.merge"):
+            first_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+            if self._next_tok is None:
+                self._next_tok = first_tok
+                self._cache = fresh
+                return
+            mask = np.zeros(bs, bool)
+            mask[newly] = True
+            self._next_tok = jnp.where(jnp.asarray(mask), first_tok,
+                                       self._next_tok)
+            self._cache = self._merge_admitted(self._cache, fresh, newly)
 
     def _retire(self, i: int) -> None:
         """Release lane ``i``: mark its request done, unpin the bank slot
@@ -1042,6 +1072,45 @@ class ServingEngine:
         self._variant_idx_dev = None
         self.metrics["retired"] += 1
 
+    @contextlib.contextmanager
+    def _round(self):
+        """One round of the continuous loop: a ``serve.step`` span (step
+        number = decode steps so far) and its wall time, less what it
+        spent off the engine's host code (``_off_host``), added to
+        ``host_seconds``."""
+        t0 = time.perf_counter()
+        self._off_host = 0.0
+        with StepTraceAnnotation("serve.step",
+                                 step_num=self.metrics["decode_steps"]):
+            try:
+                yield
+            finally:
+                self.metrics["host_seconds"] += (
+                    time.perf_counter() - t0 - self._off_host)
+
+    def _wait(self, x, span: str) -> None:
+        """``block_until_ready(x)`` inside ``span``: time blocked on the
+        device, not host time."""
+        t0 = time.perf_counter()
+        with TraceAnnotation(span):
+            jax.block_until_ready(x)
+        self._off_host += time.perf_counter() - t0
+
+    def _record_step(self, dt: float, admission_busy: bool) -> None:
+        """Hand one decode step to ``step_times`` (a caller's hook, timed
+        as the caller's and not the engine's)."""
+        if not self.record_step_times:
+            return
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation("serve.hook"):
+                # steps overlapping admission inherit the scatter the jax
+                # dependency chain ordered before them — exactly the stall
+                # the benchmark's 2x ceiling gates
+                self.step_times.append((t0, dt, admission_busy))
+        finally:
+            self._off_host += time.perf_counter() - t0
+
     def _serve_continuous(self, max_rounds: int) -> None:
         # max_rounds bounds STALLED rounds (no admission, no token, no
         # failure), not decode steps — productive rounds are already
@@ -1049,78 +1118,83 @@ class ServingEngine:
         # drains fully instead of stranding requests mid-flight
         stalls = 0
         while (self._queue or self.active()) and stalls < max_rounds:
-            # admission drain hook: commit AT MOST ONE staged overlay per
-            # step (one donated scatter dispatch, no fence) — the bounded
-            # on-thread cost of async admission (DESIGN.md §13)
-            drained = 0
-            if self.admission is not None:
-                drained = self.admission.drain(max_admits=1)
-                self.metrics["async_admits"] += drained
-            failed0 = self.metrics["failed"]
-            newly = self._admit_free_slots()
-            if newly:
-                self._prefill_admitted(newly)
-            if not self.active():
-                if not self._queue:
-                    break
-                # admissions failed this round; retry (counts as a stall
-                # unless requests were failed — retries terminate)
-                if self.metrics["failed"] > failed0 or drained:
-                    stalls = 0
-                elif self.admission is not None \
-                        and self.admission.in_flight():
-                    # every queued request is behind ingest and no lane is
-                    # decoding: sleep on pipeline progress, don't busy-spin
-                    # (terminates: ingest stages, fails, or commits once
-                    # retirements release pins)
-                    self.admission.wait_progress(0.05)
-                    stalls = 0
-                else:
-                    stalls += 1
-                continue
-            stalls = 0
-            # ONE host sync per step: every active slot has exactly one
-            # pending token in next_tok — append from the host buffer
-            host_tok = np.asarray(self._next_tok)
-            retired = []
-            for i, s in enumerate(self._slots):
-                if s is None:
+            with self._round():
+                # admission drain hook: commit AT MOST ONE staged overlay per
+                # step (one donated scatter dispatch, no fence) — the bounded
+                # on-thread cost of async admission (DESIGN.md §13)
+                drained = 0
+                if self.admission is not None:
+                    drained = self.admission.drain(max_admits=1)
+                    self.metrics["async_admits"] += drained
+                failed0 = self.metrics["failed"]
+                newly = self._admit_free_slots()
+                if newly:
+                    self._prefill_admitted(newly)
+                if not self.active():
+                    if not self._queue:
+                        break
+                    # admissions failed this round; retry (counts as a stall
+                    # unless requests were failed — retries terminate)
+                    if self.metrics["failed"] > failed0 or drained:
+                        stalls = 0
+                    elif self.admission is not None \
+                            and self.admission.in_flight():
+                        # every queued request is behind ingest and no lane is
+                        # decoding: sleep on pipeline progress, don't busy-spin
+                        # (terminates: ingest stages, fails, or commits once
+                        # retirements release pins)
+                        t0 = time.perf_counter()
+                        self.admission.wait_progress(0.05)
+                        self._off_host += time.perf_counter() - t0
+                        stalls = 0
+                    else:
+                        stalls += 1
                     continue
-                s.request.out_tokens.append(int(host_tok[i]))
-                self._note_first_token(s.request)
-                s.remaining -= 1
-                self.metrics["tokens_generated"] += 1
-                if s.remaining <= 0:
-                    retired.append(i)
-            # retire exhausted slots IMMEDIATELY — their lanes are free for
-            # the next admission wave instead of padding to the batch max
-            for i in retired:
-                self._retire(i)
-            if not (self.active() or self._queue):
-                break           # drained: skip the dangling decode
-            if not self.active():
-                continue        # lanes empty but queue pending: admit next
-            bank = self.registry.bank.tree if self.registry.bank else None
-            if self._variant_idx_dev is None:
-                self._variant_idx_dev = jnp.asarray(self._variant_idx)
-            admission_busy = drained > 0 or (
-                self.admission is not None
-                and self.admission.in_flight() > 0)
-            t0 = time.perf_counter()
-            self._next_tok, self._cache = self._call(
-                "decode_banked", self.registry.base_params, bank,
-                self._variant_idx_dev, self._next_tok, self._cache)
-            jax.block_until_ready(self._next_tok)
-            dt = time.perf_counter() - t0
-            self.metrics["decode_seconds"] += dt
-            self.metrics["decode_steps"] += 1
-            if self.record_step_times:
-                # steps overlapping admission inherit the scatter the jax
-                # dependency chain ordered before them — exactly the stall
-                # the benchmark's 2x ceiling gates
-                self.step_times.append(
-                    (time.perf_counter(), dt, admission_busy))
-        self.metrics["batches"] += 1
+                stalls = 0
+                with TraceAnnotation("serve.emit"):
+                    # ONE host sync per step: every active slot has exactly one
+                    # pending token in next_tok — append from the host buffer
+                    t0 = time.perf_counter()
+                    host_tok = np.asarray(self._next_tok)
+                    self._off_host += time.perf_counter() - t0
+                    retired = []
+                    for i, s in enumerate(self._slots):
+                        if s is None:
+                            continue
+                        s.request.out_tokens.append(int(host_tok[i]))
+                        self._note_first_token(s.request)
+                        s.remaining -= 1
+                        self.metrics["tokens_generated"] += 1
+                        if s.remaining <= 0:
+                            retired.append(i)
+                    # retire exhausted slots IMMEDIATELY — their lanes are free
+                    # for the next admission wave instead of padding to the
+                    # batch max
+                    for i in retired:
+                        self._retire(i)
+                if not (self.active() or self._queue):
+                    break           # drained: skip the dangling decode
+                if not self.active():
+                    continue        # lanes empty but queue pending: admit next
+                with TraceAnnotation("serve.decode", slots=len(
+                        set(self._variant_idx.tolist()))):
+                    bank = (self.registry.bank.tree if self.registry.bank
+                            else None)
+                    if self._variant_idx_dev is None:
+                        self._variant_idx_dev = jnp.asarray(
+                            self._variant_idx)
+                    admission_busy = drained > 0 or (
+                        self.admission is not None
+                        and self.admission.in_flight() > 0)
+                    t0 = time.perf_counter()
+                    self._next_tok, self._cache = self._call(
+                        "decode_banked", self.registry.base_params, bank,
+                        self._variant_idx_dev, self._next_tok, self._cache)
+                    self._wait(self._next_tok, "serve.decode.wait")
+                    dt = time.perf_counter() - t0
+                    self.metrics["decode_seconds"] += dt
+                    self.metrics["decode_steps"] += 1
+                self._record_step(dt, admission_busy)
 
     def _serve_speculative(self, max_rounds: int) -> None:
         """The continuous slot scheduler with the per-token decode swapped
@@ -1189,9 +1263,7 @@ class ServingEngine:
             self.metrics["decode_seconds"] += dt
             self.metrics["decode_steps"] += 1
             self.metrics["spec_rounds"] += 1
-            if self.record_step_times:
-                self.step_times.append(
-                    (time.perf_counter(), dt, admission_busy))
+            self._record_step(dt, admission_busy)
             # second host sync of the round: the accepted prefixes
             host_ver = np.asarray(ver)
             host_n = np.asarray(n_acc)
@@ -1218,7 +1290,6 @@ class ServingEngine:
             self.metrics["spec_drafted"] += k * lanes
             self.metrics["spec_accepted"] += acc_total
             self.spec.observe(k, acc_total, lanes)
-        self.metrics["batches"] += 1
 
     def _prompt_batch(self, requests: dict) -> dict:
         """Fixed-shape (batch_size, prompt_len) prefill batch: row i holds
