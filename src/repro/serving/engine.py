@@ -6,10 +6,11 @@ Two schedulers (DESIGN.md §9):
   persistent decode batch of ``batch_size`` SLOTS.  Each slot carries its
   own request, variant index (into the registry's OverlayBank — slot 0 =
   base), decode position and token budget.  Every step: free slots admit
-  queued requests (prefill-on-admit, cache rows merged in), every active
-  slot appends its pending token (one host sync per step), exhausted slots
-  retire IMMEDIATELY and free their lane, and one jitted decode serves the
-  whole heterogeneous batch through the banked fused delta GEMMs.  Requires
+  queued requests (prefill-on-admit, cache rows written into their
+  lanes), every active slot appends its pending token (one host sync per
+  step), exhausted slots retire IMMEDIATELY and free their lane, and one
+  jitted decode serves the whole heterogeneous batch through the banked
+  fused delta GEMMs.  Requires
   fused (packed-overlay) residency for every variant.
 
 * ``group`` (compatibility mode, dense residency path) — pending requests
@@ -57,7 +58,15 @@ executable resolved inside it shows as ``serve.compile``.  They record
 nothing without a profiler session.  ``metrics["host_seconds"]`` sums each
 round's wall time less its waits on the device, the step hook and
 admission sleeps; ``metrics["prefill_rows"]`` counts the rows admission
-waves admitted (each wave computes all ``batch_size`` rows).
+waves admitted and ``metrics["prefill_rows_computed"]`` the rows they
+computed (each wave's row bucket, ``serve.prefill``'s ``bucket``).
+
+Admission waves (``_prefill_admitted``): a wave of R admitted rows runs
+the smallest row bucket B >= R — the powers of two below ``batch_size``,
+and ``batch_size`` — as ONE program (``prefill_banked_fn``): a B-row
+prefill, each row's first token, and both written into the row's lane of
+the persistent batch state.  Lanes sharded over several devices keep the
+one full-width bucket, row i for lane i.
 """
 from __future__ import annotations
 
@@ -220,10 +229,27 @@ class ServingEngine:
 
         # banked pair: ONE compiled prefill/decode serves every mix of
         # resident variants — the bank tree and per-row variant_idx are
-        # plain jit arguments, so admissions/evictions never recompile
-        def prefill_banked_fn(params, bank, vidx, batch):
-            return model.prefill(params, batch, max_len, overlay=bank,
-                                 variant_idx=vidx)
+        # plain jit arguments, so admissions/evictions never recompile.
+        # The prefill is an admission wave: it prefills the wave's rows,
+        # takes each row's first token, and writes both into lane
+        # ``lanes[r]`` of the batch state (a pad row's lane is out of
+        # range: its write is dropped)
+        cache_axes = [sp.index("act_batch") for sp in jax.tree.leaves(
+            model.cache_pspecs(), is_leaf=lambda x: isinstance(x, tuple))]
+
+        def prefill_banked_fn(params, bank, vidx, batch, lanes, token,
+                              cache):
+            logits, fresh = model.prefill(params, batch, max_len,
+                                          overlay=bank, variant_idx=vidx)
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            old, treedef = jax.tree_util.tree_flatten(cache)
+            new = jax.tree_util.tree_leaves(fresh)
+            assert len(cache_axes) == len(old) == len(new), \
+                "cache_pspecs out of sync with the cache structure"
+            return (_write_rows(token, first, lanes, 0),
+                    jax.tree_util.tree_unflatten(treedef, [
+                        _write_rows(o, f, lanes, ax)
+                        for o, f, ax in zip(old, new, cache_axes)]))
 
         def decode_banked_fn(params, bank, vidx, token, cache):
             logits, cache = model.decode_step(params, token, cache,
@@ -239,7 +265,8 @@ class ServingEngine:
         self._roles = {"prefill": ("params", "overlay", "batch"),
                        "decode": ("params", "overlay", "token", "cache"),
                        "prefill_banked": ("params", "overlay", "token",
-                                          "batch"),
+                                          "batch", "token", "token",
+                                          "cache"),
                        "decode_banked": ("params", "overlay", "token",
                                          "token", "cache")}
         # speculative rounds (serving/speculative.py): one executable per
@@ -290,6 +317,32 @@ class ServingEngine:
             self._logits_sh = NamedSharding(
                 mesh, PartitionSpec(*(list(tok_spec) + [None])))
             self._batch_axes = model.batch_pspecs("prefill")
+        # admission-wave row buckets, each its own step kind (and program,
+        # all named prefill_banked_fn): the powers of two below batch_size,
+        # and batch_size.  Lanes sharded over several devices keep the one
+        # full-width bucket — a pod-local slot is read on its lane's pod,
+        # and data-parallel rows must divide the lane shards
+        buckets = [1 << k for k in range(batch_size.bit_length())
+                   if 1 << k < batch_size] + [batch_size]
+        if mesh is not None and \
+                self._tok_sh.shard_shape((batch_size,))[0] < batch_size:
+            buckets = [batch_size]
+        self._wave_kinds = {b: "prefill_banked" if b == batch_size
+                            else f"prefill_banked/r{b}" for b in buckets}
+        for kind in self._wave_kinds.values():
+            self._fns[kind] = self._fns["prefill_banked"]
+            self._roles[kind] = self._roles["prefill_banked"]
+        # the lane state before the first wave — every lane's pending
+        # token and an empty cache row, placed as the decode step keeps
+        # them — staged through the persistent cache like the steps
+        self._empty_lanes = CC.CachedCallable(
+            jax.jit(lambda: (jnp.zeros((batch_size,), jnp.int32),
+                             model.init_cache(batch_size, max_len)),
+                    out_shardings=(None if mesh is None
+                                   else (self._tok_sh, self._cache_sh))),
+            ("engine-lanes", repr(model.cfg), batch_size, max_len,
+             CC.mesh_fp(mesh)),
+            cache=self.compile_cache)
         # continuous-scheduler state (persists across run_until_drained
         # calls: the decode batch is a long-lived object)
         self._slots: list[Optional[_Slot]] = [None] * batch_size
@@ -303,14 +356,14 @@ class ServingEngine:
              else 0 for i in range(batch_size)], np.int32)
         self._variant_idx = self._base_vidx.copy()
         self._variant_idx_dev = None     # device copy, rebuilt on change
-        self._merge_jit = None           # built on first admission merge
         # bounded TTFT reservoir behind the p50/p99 status() reports:
         # first _ttft_cap samples fill it, later ones overwrite in
         # arrival order (deterministic sliding window, no RNG)
         self._ttft_cap = 1024
         self._ttft_samples: list = []
         self.metrics = {"tokens_generated": 0,
-                        "prefills": 0, "prefill_rows": 0, "failed": 0,
+                        "prefills": 0, "prefill_rows": 0,
+                        "prefill_rows_computed": 0, "failed": 0,
                         "admitted": 0, "retired": 0, "decode_steps": 0,
                         "prefill_seconds": 0.0, "decode_seconds": 0.0,
                         "host_seconds": 0.0,
@@ -354,7 +407,10 @@ class ServingEngine:
             # pin exactly those (None for the dense overlay-free trace)
             return jax.tree.map(lambda l: l.sharding, arg)
         if role == "token":
-            return self._tok_sh
+            if arg.shape[0] == self.batch_size:
+                return self._tok_sh
+            return NamedSharding(self.mesh, resolve_spec(
+                arg.shape, ("act_batch",), self._rules, self.mesh))
         if role == "cache":
             return self._cache_sh
         if role == "batch":
@@ -391,7 +447,9 @@ class ServingEngine:
             return jax.jit(self._fns[kind])
         in_sh = tuple(self._arg_sharding(role, arg)
                       for role, arg in zip(self._roles[kind], args))
-        if kind.startswith("prefill"):
+        if kind.startswith("prefill_banked"):
+            out_sh = (self._tok_sh, self._cache_sh)
+        elif kind.startswith("prefill"):
             out_sh = (self._logits_sh, self._cache_sh)
         elif kind.startswith("spec_k"):
             # (ver (B,T), n_acc (B,), next_tok (B,), cache): the token
@@ -451,9 +509,11 @@ class ServingEngine:
 
     def step_hlo(self) -> dict:
         """Optimized HLO text of each step program resolved so far, by
-        kind.  Its instructions carry the op metadata that a chip's
-        profiler trace lacks (the name stack, with the ``base_gemm`` and
-        ``attention`` scopes), under the names the trace gives its ops."""
+        kind (``prefill_banked/r<B>`` for a wave bucket of B rows below
+        ``batch_size``).  Its instructions carry the op metadata that a
+        chip's profiler trace lacks (the name stack, with the
+        ``base_gemm`` and ``attention`` scopes), under the names the
+        trace gives its ops."""
         return {kind: exe.as_text() for (kind, _), exe in self._exe.items()}
 
     def _call(self, kind: str, *args):
@@ -602,7 +662,8 @@ class ServingEngine:
         default the plain pair (base model / dense residents), the fused
         pair (single-variant packed overlay + params view), the banked
         pair (the continuous scheduler's overlay bank + per-row
-        variant_idx) plus the admission cache-merge, and — under
+        variant_idx: the decode step and every admission-wave bucket)
+        plus the lane state's first cache, and — under
         ``scheduler="speculative"`` — one speculative round per draft
         length on the adaptive ladder, in bank-resident AND bank-empty
         flavours.  With a persistent compile cache attached, a warm
@@ -722,30 +783,43 @@ class ServingEngine:
         bank = self._bank_struct(ctx)
         warm = ctx["warm"]
         base, token, cache = ctx["base"], ctx["token"], ctx["cache"]
-        vidx, batch = ctx["vidx"], ctx["batch"]
+        vidx = ctx["vidx"]
         # pre-first-admission state: the continuous scheduler serves
         # base-only traffic with bank=None until a variant lands
-        warm("banked-empty", "prefill_banked", (base, None, vidx, batch))
+        self._warm_waves(ctx, "banked-empty", None)
         warm("banked-empty", "decode_banked",
              (base, None, vidx, token, cache))
-        warm("banked", "prefill_banked", (base, bank, vidx, batch))
+        self._warm_waves(ctx, "banked", bank)
         warm("banked", "decode_banked", (base, bank, vidx, token, cache))
         if self.scheduler in ("continuous", "speculative"):
-            if self._merge_jit is None:
-                self._merge_jit = self._make_merge()
-            ctx["outcomes"]["banked/merge"] = self._merge_jit.aot(
-                cache, cache,
-                jax.ShapeDtypeStruct((self.batch_size,), jnp.bool_))
+            ctx["outcomes"]["banked/lanes"] = self._empty_lanes.aot()
+
+    def _warm_waves(self, ctx, tag: str, bank) -> None:
+        """Every admission-wave bucket's program for one bank state."""
+        for rows, kind in self._wave_kinds.items():
+            ctx["warm"](tag, kind, self._wave_struct(
+                rows, ctx["base"], bank, ctx["token"], ctx["cache"]))
+
+    def _wave_struct(self, rows: int, params, bank, token, cache) -> tuple:
+        """Arguments of the ``rows``-row wave program, the per-row ones
+        abstract."""
+        row = jax.ShapeDtypeStruct((rows,), jnp.int32)
+        batch = jax.eval_shape(lambda: self._prompt_batch({}, rows))
+        return (params, bank, row, batch, row, token, cache)
 
     def _warm_speculative(self, ctx) -> None:
         """One speculative round per ladder rung (each k is its own scan
         length, hence its own executable), in both the bank-resident and
         the pre-first-admission (bank=None) flavours — the two new step
-        shapes the scheduler dispatches."""
+        shapes the scheduler dispatches — and the admission waves it
+        shares with the continuous scheduler."""
         warm = ctx["warm"]
         base, token, cache = ctx["base"], ctx["token"], ctx["cache"]
         vidx = ctx["vidx"]
         bank = self._bank_struct(ctx) if ctx["delta_paths"] else None
+        self._warm_waves(ctx, "spec-empty", None)
+        if bank is not None:
+            self._warm_waves(ctx, "spec", bank)
         for k in self.spec.ladder:
             warm("spec-empty", f"spec_k{k}",
                  (base, None, vidx, token, cache))
@@ -875,47 +949,6 @@ class ServingEngine:
             self._done[r.rid] = r
 
     # -- continuous slot scheduler (mixed-variant batches) -------------------
-    def _merge_admitted(self, old, fresh, admit_rows: list):
-        """Merge freshly prefilled cache rows into the persistent batch
-        cache.  The batch axis of every cache leaf is located via the
-        model's cache_pspecs ("act_batch" logical axis) — per-row slot_pos
-        and pos make every leaf row-separable, so admission is a pure
-        select along that axis.  One jitted call per admission wave."""
-        if old is None:
-            return fresh
-        mask = np.zeros(self.batch_size, bool)
-        mask[admit_rows] = True
-        if self._merge_jit is None:
-            self._merge_jit = self._make_merge()
-        return self._merge_jit(old, fresh, jnp.asarray(mask))
-
-    def _make_merge(self):
-        """The admission cache-merge jit, staged through the persistent
-        cache like the step pairs (it compiles on the SECOND admission
-        wave — steady-state latency, not first-token, but a restart
-        should not re-pay it either)."""
-        bs = self.batch_size
-        specs = jax.tree.leaves(self.model.cache_pspecs(),
-                                is_leaf=lambda x: isinstance(x, tuple))
-
-        def merge(old, fresh, mask):
-            old_leaves, treedef = jax.tree_util.tree_flatten(old)
-            fresh_leaves, _ = jax.tree_util.tree_flatten(fresh)
-            assert len(specs) == len(old_leaves) == len(fresh_leaves), \
-                "cache_pspecs out of sync with the cache structure"
-            out = []
-            for o, f, sp in zip(old_leaves, fresh_leaves, specs):
-                shape = [1] * o.ndim
-                shape[sp.index("act_batch")] = bs
-                out.append(jnp.where(mask.reshape(shape), f, o))
-            return jax.tree_util.tree_unflatten(treedef, out)
-
-        return CC.CachedCallable(
-            jax.jit(merge),
-            ("engine-merge", repr(self.model.cfg), bs, self.max_len,
-             CC.mesh_fp(self.mesh)),
-            cache=self.compile_cache)
-
     def _lane_pod(self, i: int) -> int:
         """Pod owning batch lane ``i``: act_batch shards pod-major over
         ("pod", "data"), so lanes block-partition into contiguous per-pod
@@ -1029,36 +1062,45 @@ class ServingEngine:
         return newly
 
     def _prefill_admitted(self, newly: list) -> None:
-        """Prefill-on-admit: one fixed-shape (batch_size, prompt_len)
-        prefill per admission wave; only the newly admitted rows of the
-        resulting cache/logits are merged into the persistent batch."""
+        """Prefill-on-admit: one program per admission wave, on the
+        smallest row bucket that holds the admitted lanes.  Below full
+        width, row r is the r-th admitted lane and pad rows write nothing;
+        at full width, row i is lane i."""
         bs = self.batch_size
-        with TraceAnnotation("serve.prefill", rows=len(newly), lanes=bs):
-            pvidx = self._base_vidx.copy()
-            for i in newly:
-                pvidx[i] = self._slots[i].variant_slot
+        rows = next(b for b in self._wave_kinds if b >= len(newly))
+        kind = self._wave_kinds[rows]
+        with TraceAnnotation("serve.prefill", rows=len(newly), lanes=bs,
+                             bucket=rows):
+            place = ({i: i for i in newly} if rows == bs
+                     else dict(enumerate(newly)))       # row -> lane
+            pvidx = self._base_vidx[:rows].copy()
+            lanes = np.full(rows, bs, np.int32)
+            for r, i in place.items():
+                pvidx[r] = self._slots[i].variant_slot
+                lanes[r] = i
             batch = self._prompt_batch(
-                {i: self._slots[i].request for i in newly})
+                {r: self._slots[i].request for r, i in place.items()}, rows)
+            if self._cache is None:
+                self._next_tok, self._cache = self._empty_lanes()
             bank = self.registry.bank.tree if self.registry.bank else None
             t0 = time.perf_counter()
-            last_logits, fresh = self._call(
-                "prefill_banked", self.registry.base_params, bank,
-                jnp.asarray(pvidx), batch)
-            self._wait(last_logits, "serve.prefill.wait")
+            if (kind, jax.tree_util.tree_structure(bank)) not in self._exe:
+                # first wave of this bank structure: resolve every bucket
+                # now, so that no later wave compiles
+                for b, k in self._wave_kinds.items():
+                    self._get_exe(k, self._wave_struct(
+                        b, self.registry.base_params, bank, self._next_tok,
+                        self._cache))
+            tok, cache = self._call(
+                kind, self.registry.base_params, bank, jnp.asarray(pvidx),
+                batch, jnp.asarray(lanes), self._next_tok, self._cache)
+            self._wait(tok, "serve.prefill.wait")
             self.metrics["prefill_seconds"] += time.perf_counter() - t0
             self.metrics["prefills"] += 1
             self.metrics["prefill_rows"] += len(newly)
+            self.metrics["prefill_rows_computed"] += rows
         with TraceAnnotation("serve.merge"):
-            first_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            if self._next_tok is None:
-                self._next_tok = first_tok
-                self._cache = fresh
-                return
-            mask = np.zeros(bs, bool)
-            mask[newly] = True
-            self._next_tok = jnp.where(jnp.asarray(mask), first_tok,
-                                       self._next_tok)
-            self._cache = self._merge_admitted(self._cache, fresh, newly)
+            self._next_tok, self._cache = tok, cache
 
     def _retire(self, i: int) -> None:
         """Release lane ``i``: mark its request done, unpin the bank slot
@@ -1291,12 +1333,14 @@ class ServingEngine:
             self.metrics["spec_accepted"] += acc_total
             self.spec.observe(k, acc_total, lanes)
 
-    def _prompt_batch(self, requests: dict) -> dict:
-        """Fixed-shape (batch_size, prompt_len) prefill batch: row i holds
-        requests[i]'s prompt tail, zero-padded; unmapped rows stay zero.
-        The ONE place prompt padding happens — both schedulers must build
-        bit-identical batches or their tokens diverge."""
-        bs = self.batch_size
+    def _prompt_batch(self, requests: dict, rows: Optional[int] = None
+                      ) -> dict:
+        """Fixed-shape (rows, prompt_len) prefill batch, ``batch_size``
+        rows by default: row i holds requests[i]'s prompt tail,
+        zero-padded; unmapped rows stay zero.  The ONE place prompt
+        padding happens — both schedulers must build bit-identical
+        batches or their tokens diverge."""
+        bs = self.batch_size if rows is None else rows
         toks = np.zeros((bs, self.prompt_len), np.int32)
         for i, r in requests.items():
             p = r.tokens[-self.prompt_len:]
@@ -1314,3 +1358,15 @@ class ServingEngine:
             return {"image_embeds": jnp.zeros(
                 (bs, cfg.num_image_tokens, cfg.d_model), jnp.float32)}
         return {}
+
+
+def _write_rows(old, fresh, lanes, axis: int):
+    """``old`` with row r of ``fresh`` (along ``axis``) written at index
+    ``lanes[r]``; a row whose lane is out of range writes nothing.  A
+    full-width ``fresh`` holds lane i in row i: an elementwise select,
+    which keeps a sharded lane axis local.  A narrower one scatters."""
+    n = old.shape[axis]
+    if fresh.shape[axis] == n:
+        shape = [n if d == axis else 1 for d in range(old.ndim)]
+        return jnp.where((lanes == jnp.arange(n)).reshape(shape), fresh, old)
+    return old.at[(slice(None),) * axis + (lanes,)].set(fresh, mode="drop")

@@ -371,6 +371,74 @@ def test_scheduler_slot_reuse_preserves_isolation():
     assert eng.result(late).out_tokens == solo.result(ref).out_tokens
 
 
+@pytest.fixture(scope="module")
+def four_lanes():
+    """Two 4-lane continuous engines over one base and two variants: one
+    to serve mixed waves, one to serve each request alone."""
+    model, base, dm1, dm2 = _pair3("deepseek-7b")
+
+    def make_engine():
+        reg = VariantRegistry(base, mode="fused", bank_size=4)
+        reg.register("v1", dm1)
+        reg.register("v2", dm2)
+        return ServingEngine(model, reg, batch_size=4, prompt_len=8,
+                             max_len=32, scheduler="continuous")
+    return make_engine(), make_engine()
+
+
+@pytest.mark.parametrize("rows,bucket", [(1, 1), (2, 2), (3, 4)])
+def test_a_wave_computes_the_smallest_row_bucket_that_holds_it(
+        four_lanes, rows, bucket):
+    """Idle lanes are not prefilled: a wave admitting R rows computes the
+    smallest of the buckets 1, 2, 4 that holds R."""
+    eng, _ = four_lanes
+    before = dict(eng.metrics)
+    for i in range(rows):
+        eng.submit(np.arange(1 + i, 7 + i), variant=("v1", "__base__",
+                                                     "v2")[i],
+                   max_new_tokens=2)
+    eng.run_until_drained()
+    delta = {k: eng.metrics[k] - before[k] for k in
+             ("prefills", "prefill_rows", "prefill_rows_computed")}
+    assert delta == {"prefills": 1, "prefill_rows": rows,
+                     "prefill_rows_computed": bucket}
+
+
+@pytest.mark.parametrize("budgets,lanes", [
+    # lanes 1 and 3 admitted while lanes 0 and 2 decode: bucket 2
+    ((9, 2, 9, 2, 3, 4), [1, 3]),
+    # lanes 0, 1 and 3 admitted while lane 2 decodes: bucket 4, one pad row
+    ((2, 2, 9, 2, 3, 4, 5), [0, 1, 3]),
+])
+def test_a_wave_into_scattered_lanes_leaves_live_lanes_alone(
+        four_lanes, budgets, lanes):
+    """Rows admitted into non-contiguous lanes, beside lanes that are
+    decoding, and the pad row of a bucket: every request decodes exactly
+    the tokens it decodes alone."""
+    eng, solo = four_lanes
+    variants = ["v1", "__base__", "v2"]
+    prompts = [np.arange(2 + i, 8 + i) for i in range(len(budgets))]
+    waves = []
+    prefill = eng._prefill_admitted
+
+    def recording(newly):
+        waves.append(list(newly))
+        prefill(newly)
+    eng._prefill_admitted = recording
+    try:
+        rids = [eng.submit(p, variant=variants[i % 3], max_new_tokens=m)
+                for i, (p, m) in enumerate(zip(prompts, budgets))]
+        eng.run_until_drained()
+    finally:
+        del eng._prefill_admitted
+    assert waves == [[0, 1, 2, 3], lanes]
+    for i, (p, m) in enumerate(zip(prompts, budgets)):
+        ref = solo.submit(p, variant=variants[i % 3], max_new_tokens=m)
+        solo.run_until_drained()
+        assert eng.result(rids[i]).out_tokens == \
+            solo.result(ref).out_tokens, i
+
+
 def test_scheduler_matches_grouped_serving_tokens():
     """End to end: mixed continuous batches generate exactly the tokens
     the grouped-by-variant engine generates per request."""

@@ -142,6 +142,9 @@ def test_a_trace_holds_every_serve_span_nested_in_its_step(pair, tmp_path):
     assert len(waves) == eng.metrics["prefills"]
     assert sum(s["stats"]["rows"] for s in waves) == 5
     assert {s["stats"]["lanes"] for s in waves} == {3}
+    assert all(s["stats"]["bucket"] >= s["stats"]["rows"] for s in waves)
+    assert sum(s["stats"]["bucket"] for s in waves) == \
+        eng.metrics["prefill_rows_computed"]
     admits = [s for s in spans if s["name"] == "serve.admit"]
     assert sum(s["stats"]["admitted"] for s in admits) == 5
     assert admits[0]["stats"]["queued"] == 2
@@ -151,7 +154,9 @@ def test_scopes_name_the_base_gemm_and_attention_ops(pair):
     eng = _engine(pair)
     eng.run_until_drained()
     hlo = eng.step_hlo()
-    assert set(hlo) == {"prefill_banked", "decode_banked"}
+    # one admission-wave program per row bucket (1, 2 and all 3 lanes)
+    assert set(hlo) == {"prefill_banked", "prefill_banked/r1",
+                        "prefill_banked/r2", "decode_banked"}
     for text in hlo.values():
         stacks = [line.split('op_name="')[1].split('"')[0]
                   for line in text.splitlines() if 'op_name="' in line]

@@ -280,6 +280,59 @@ def test_engine_sharded_greedy_token_parity():
     assert run(mesh) == run(None)
 
 
+def test_engine_sharded_lanes_keep_full_width_waves():
+    """Lanes sharded over the data axis keep the one full-width admission
+    wave (row i on lane i's shard): a wave admitting one row still
+    computes every lane, no smaller bucket is compiled, and tokens match
+    the single-device engine.  Runs in a child with four host devices, so
+    that the tier-1 single-device run covers it too."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        import sys; sys.path[:0] = ["src", "tests"]
+        import jax, numpy as np
+        from jax.sharding import Mesh
+        from repro.serving import Deployment
+        from test_sharded_serving import _pair
+        model, base, axes, dm1, dm2 = _pair(layers=2)
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("data", "model"))
+        def run(mesh_or_none):
+            dep = Deployment(model, base, batch_size=2, prompt_len=8,
+                             max_len=32, bank_size=4, mesh=mesh_or_none,
+                             param_axes=axes if mesh_or_none else None)
+            dep.publish("v1", dm1)
+            dep.publish("v2", dm2)
+            rids = [dep.submit(np.arange(1, 7), variant=v, max_new_tokens=m)
+                    for v, m in [("v1", 2), ("__base__", 5), ("v2", 3)]]
+            dep.drain()
+            eng = dep.engine
+            out = ([dep.result(r).out_tokens for r in rids],
+                   {k: eng.metrics[k] for k in ("prefills", "prefill_rows",
+                                                "prefill_rows_computed")},
+                   sorted(eng.step_hlo()))
+            dep.close()
+            return out
+        got, counts, kinds = run(mesh)
+        want, single, _ = run(None)
+        assert got == want, (got, want)
+        assert counts == {"prefills": 2, "prefill_rows": 3,
+                          "prefill_rows_computed": 4}, counts
+        assert kinds == ["decode_banked", "prefill_banked"], kinds
+        assert single["prefill_rows_computed"] == 3, single
+        print("OK")
+    """)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, cwd=root, timeout=600)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr[-3000:]
+
+
 def test_engine_sharded_group_mode_parity():
     """The group scheduler (dense + fused residency) also runs sharded:
     same tokens as single-device for both residency modes."""
