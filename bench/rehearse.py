@@ -3,13 +3,17 @@ reckon the peak device memory of a cell, before any chip run.
 
     JAX_PLATFORMS=cpu python3 bench/rehearse.py <config> [<config> ...]
 
-Compiles the program's banked prefill and decode step (the pair the
-continuous scheduler runs) and the admission merge at the configuration's
-shapes for one chip of a described ``v5e:2x2`` topology, prints each
-program's ``memory_analysis``, and adds what lives beside them while they
-run: the base, the bank, and the engine's batch KV cache (the prefill
-builds a fresh cache beside it, and the merge writes a third).  No chip
-runs: memory only, never a time.
+Compiles the programs the continuous scheduler runs, at the
+configuration's shapes, for one chip of a described ``v5e:2x2`` topology:
+the banked decode step and one admission wave per row bucket (the powers
+of two below the lanes, and the lanes), each wave a prefill of its rows
+whose first tokens and cache rows are written into the lanes' state.  It
+prints each program's ``memory_analysis`` and the device bytes of the
+deployment (``work.params_bytes_summary``: base, bank and the lanes' KV
+cache), and estimates each program's peak as those bytes plus its outputs
+and temporaries: a wave holds the old cache (an argument), the new cache
+(its output) and its rows' prefill (temporaries).  No chip runs: memory
+only, never a time.
 """
 from __future__ import annotations
 
@@ -23,29 +27,39 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
         sys.path.insert(0, _p)
 
 
-def rehearse(conf: dict) -> dict:
+def wave_buckets(lanes: int) -> list:
+    """The engine's admission-wave row buckets for ``lanes`` lanes."""
+    return [1 << k for k in range(lanes.bit_length())
+            if 1 << k < lanes] + [lanes]
+
+
+def rehearse(conf: dict, sharding=None) -> dict:
+    """Memory of the served programs on ``sharding``'s device; by default
+    one chip of a described v5e, with the Mosaic kernels compiled."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
-    from bench import system, work
+    from bench import system, weights, work
     from repro.core import quantize as Q
-    from repro.kernels import ops as K
     from repro.models import build_model
     from repro.models import delta_overlay as DO
+    from repro.serving.engine import _write_rows
 
-    K._interpret = lambda: False        # compile the Mosaic kernels
-    jax.config.update("jax_enable_compilation_cache", False)
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-    sh = SingleDeviceSharding(topo.devices[0])
+    if sharding is None:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        from repro.kernels import ops as K
+        K._interpret = lambda: False        # compile the Mosaic kernels
+        jax.config.update("jax_enable_compilation_cache", False)
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        sharding = SingleDeviceSharding(topo.devices[0])
     d = conf["deployment"]
     lanes, plen, max_len = d["lanes"], d["prompt_len"], d["max_len"]
     model = build_model(system.program_config(conf))
     shapes = system.served_shapes(model)
-    from bench.weights import is_target
-    paths = sorted(p for p, s in shapes.items() if is_target(p, s))
+    paths = weights.variant_paths(shapes, conf.get("leaf_roles"))
     flat = {p: jax.ShapeDtypeStruct(s, jnp.bfloat16) for p, s in
             shapes.items()}
     flat = Q.quantize_struct(flat, paths)
@@ -53,61 +67,53 @@ def rehearse(conf: dict) -> dict:
 
     def place(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=sh), tree)
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    def ints(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding)
 
     params = place(system.program_params(model, {
         p: ({"q": w.q, "scale": w.scale} if isinstance(w, Q.QuantWeight)
             else w) for p, w in flat.items()}))
     bank = place(bank)
-    vidx = jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=sh)
-    token = vidx
-    batch = {"tokens": jax.ShapeDtypeStruct((lanes, plen), jnp.int32,
-                                            sharding=sh)}
     cache = place(jax.eval_shape(lambda: model.init_cache(lanes, max_len)))
+    axes = [sp.index("act_batch") for sp in jax.tree.leaves(
+        model.cache_pspecs(), is_leaf=lambda x: isinstance(x, tuple))]
 
-    def prefill(p, b, v, x):
-        return model.prefill(p, x, max_len, overlay=b, variant_idx=v)
+    def wave(p, b, v, x, rows_lane, token, c):   # engine.prefill_banked_fn
+        logits, fresh = model.prefill(p, x, max_len, overlay=b,
+                                      variant_idx=v)
+        first = jnp.argmax(logits, -1).astype(jnp.int32)
+        old, tdef = jax.tree_util.tree_flatten(c)
+        return (_write_rows(token, first, rows_lane, 0),
+                jax.tree_util.tree_unflatten(tdef, [
+                    _write_rows(o, f, rows_lane, ax) for o, f, ax in
+                    zip(old, jax.tree_util.tree_leaves(fresh), axes)]))
 
     def decode(p, b, v, t, c):
         lg, c = model.decode_step(p, t, c, overlay=b, variant_idx=v)
         return jnp.argmax(lg, -1).astype(jnp.int32), c
 
-    specs = jax.tree.leaves(model.cache_pspecs(),
-                            is_leaf=lambda x: isinstance(x, tuple))
-
-    def merge(old, fresh, mask):        # the engine's admission merge
-        o_l, tdef = jax.tree_util.tree_flatten(old)
-        f_l = jax.tree_util.tree_leaves(fresh)
-        out = []
-        for o, f, sp in zip(o_l, f_l, specs):
-            shape = [1] * o.ndim
-            shape[sp.index("act_batch")] = lanes
-            out.append(jnp.where(mask.reshape(shape), f, o))
-        return jax.tree_util.tree_unflatten(tdef, out)
-
-    out = {}
-    for name, fn, args in (
-            ("prefill_banked", prefill, (params, bank, vidx, batch)),
-            ("decode_banked", decode, (params, bank, vidx, token, cache)),
-            ("merge", merge, (cache, cache, jax.ShapeDtypeStruct(
-                (lanes,), jnp.bool_, sharding=sh)))):
+    programs = {"decode_banked": (decode, (params, bank, ints(lanes),
+                                           ints(lanes), cache))}
+    for rows in wave_buckets(lanes):
+        batch = {"tokens": jax.ShapeDtypeStruct((rows, plen), jnp.int32,
+                                                sharding=sharding)}
+        kind = ("prefill_banked" if rows == lanes
+                else f"prefill_banked/r{rows}")
+        programs[kind] = (wave, (params, bank, ints(rows), batch, ints(rows),
+                                 ints(lanes), cache))
+    plan = work.params_bytes_summary(conf, d["bank_size"], lanes * max_len)
+    out, peak = {}, {}
+    for kind, (fn, args) in programs.items():
         ma = jax.jit(fn).lower(*args).compile().memory_analysis()
-        out[name] = {k: int(getattr(ma, k)) for k in (
+        out[kind] = {k: int(getattr(ma, k)) for k in (
             "argument_size_in_bytes", "output_size_in_bytes",
             "temp_size_in_bytes", "alias_size_in_bytes")}
-    plan = work.params_bytes_summary(conf, d["bank_size"], lanes * max_len)
-    kv = lanes * max_len * plan["kv_per_token"]
-    resident = plan["base"] + d["bank_size"] * plan["slot"]
-    pf, dc, mg = out["prefill_banked"], out["decode_banked"], out["merge"]
+        peak[kind] = (plan["total"] + out[kind]["output_size_in_bytes"]
+                      + out[kind]["temp_size_in_bytes"])
     out["plan"] = plan
-    out["peak_estimate"] = {
-        "prefill": resident + kv + pf["output_size_in_bytes"]
-        + pf["temp_size_in_bytes"],
-        "decode": resident + kv + dc["output_size_in_bytes"]
-        + dc["temp_size_in_bytes"],
-        "merge": resident + 2 * kv + mg["output_size_in_bytes"]
-        + mg["temp_size_in_bytes"],
-    }
+    out["peak_estimate"] = peak
     return out
 
 

@@ -14,6 +14,34 @@ Each lives in a file of its own under ``bench/``:
 
 A new cell, configuration, mix or metric is therefore new files plus new
 ``BENCHMARK.json`` entries; no existing file changes.
+
+A configuration file carries the whole architecture it runs, under the
+published ``config.json`` names.  Its keys are of two kinds:
+
+* harness keys (``HARNESS_KEYS``: the name, the sources, what was cut and
+  assumed, ``published``, ``leaf_roles``, the program entry, the
+  reference, the deployment, the precision), which never reach the
+  program;
+* architecture keys, each of which ``system.program_config`` hands to the
+  program's ``ModelConfig`` in exactly one way: renamed
+  (``system.PUBLISHED_TO_PROGRAM``), under its own name where that is a
+  ``ModelConfig`` field (``head_dim``, ``rope_theta``, ...), or matched
+  against a value the program implements without a field
+  (``system.IMPLIED``: ``scoring_func`` "softmax", ``rope_scaling`` null,
+  ...).  Any other key, or another value, is refused by name before a
+  weight is made, as is a field of the program's registry entry that
+  holds architecture the file leaves out.  ``work.py`` counts operations
+  and bytes from the same keys, and refuses one whose effect on the
+  counts it does not model.
+
+A cut is stated: every key in ``reduced`` has its published value in
+``published``, and every key there is in ``reduced``.  An expert share
+(the ``model-configs`` guide, section 4) lists ``n_routed_experts`` in
+``reduced``: the file's count is the experts this chip holds, experts
+``[0, held)``, ``published.n_routed_experts`` is the router's width, and
+``deployment.expert_parallel`` is the number of chips that share each
+layer.  ``leaf_roles`` (``{leaf name: role}``) names the served leaves
+the default table of ``weights.py`` does not.
 """
 from __future__ import annotations
 
@@ -23,6 +51,10 @@ import pathlib
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
+
+HARNESS_KEYS = frozenset({"name", "source", "paper", "model_type", "reduced",
+                          "assumed", "published", "leaf_roles", "program",
+                          "reference", "deployment", "precision"})
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:

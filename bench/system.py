@@ -3,8 +3,8 @@
 This is the one file that knows the program's entry points: its model
 registry and ``ModelConfig``, its parameter tree and ``QuantWeight``, the
 ``DeltaModel`` a variant is published as, and ``Deployment`` itself.  A
-configuration states the published sizes under their published names; the
-table below maps each to the program's field.
+configuration states its architecture under the published names, and
+``program_config`` hands every key to the program or refuses it by name.
 """
 from __future__ import annotations
 
@@ -14,12 +14,12 @@ import jax
 import numpy as np
 
 from bench import weights as W
+from bench.spec import HARNESS_KEYS
 
 PUBLISHED_TO_PROGRAM = {
     "hidden_size": "d_model", "intermediate_size": "d_ff",
     "num_hidden_layers": "num_layers", "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "vocab_size": "vocab_size",
-    "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
+    "num_key_value_heads": "num_kv_heads", "rms_norm_eps": "norm_eps",
     "max_position_embeddings": "max_seq_len",
     "moe_intermediate_size": "moe_d_ff", "n_routed_experts": "num_experts",
     "n_shared_experts": "num_shared_experts",
@@ -28,24 +28,101 @@ PUBLISHED_TO_PROGRAM = {
     "tie_word_embeddings": "tie_embeddings",
 }
 
+# published keys the program implements at one value, without a field:
+# SwiGLU, no attention bias, softmax gates renormalized over the top-k,
+# plain top-k over ungrouped experts, an MoE block in every layer after the
+# dense ones, full-rank queries, unscaled RoPE, no multi-token head, and a
+# load-balancing loss over the batch (models/moe.py)
+IMPLIED = {
+    "hidden_act": "silu", "attention_bias": False, "norm_topk_prob": True,
+    "scoring_func": "softmax", "topk_method": "greedy",
+    "routed_scaling_factor": 1, "n_group": 1, "topk_group": 1,
+    "moe_layer_freq": 1, "q_lora_rank": None, "rope_scaling": None,
+    "num_nextn_predict_layers": 0, "ep_size": 1, "seq_aux": False,
+}
+
+# fields of ModelConfig that say how the program runs, not what it computes
+RUNTIME_FIELDS = frozenset({"name", "family", "remat", "scan_layers",
+                            "param_dtype", "compute_dtype",
+                            "capacity_factor", "router_aux_weight"})
+
 
 def program_config(conf: dict):
-    """The program's ModelConfig for a configuration file."""
+    """The program's ModelConfig for a configuration file.
+
+    Every architecture key of the file reaches it (``spec.py``), and the
+    registry entry ``program.arch`` gives only the runtime fields.  Raises
+    ``ValueError`` naming every key refused, one a line, before anything
+    is built."""
     from repro.configs import get_config
-    cfg = get_config(conf["program"]["arch"])
-    kw = {field: conf[key] for key, field in PUBLISHED_TO_PROGRAM.items()
-          if key in conf}
-    kw["head_dim"] = conf["hidden_size"] // conf["num_attention_heads"]
+    from repro.configs.base import ModelConfig
+    fields = {f.name: f for f in dataclasses.fields(ModelConfig)}
+    refused, kw = [], {}
+    for key, value in conf.items():
+        if key in HARNESS_KEYS:
+            continue
+        field = PUBLISHED_TO_PROGRAM.get(key, key)
+        if field in fields and field not in RUNTIME_FIELDS:
+            if field in kw:
+                refused.append(f"{key} = {value!r}: sets {field}, which "
+                               "another key of the file sets")
+            kw[field] = value
+        elif key not in IMPLIED:
+            refused.append(f"{key} = {value!r}: no architecture field of "
+                           "the program's ModelConfig takes it")
+        elif not _same(value, IMPLIED[key]):
+            refused.append(f"{key} = {value!r}: the program implements "
+                           f"{IMPLIED[key]!r} only")
+    if kw.get("head_dim") is None:
+        kw["head_dim"] = conf["hidden_size"] // conf["num_attention_heads"]
+
+    reduced, published = set(conf.get("reduced", [])), conf.get(
+        "published", {})
+    refused += [f"{k}: in reduced, with no published value"
+                for k in sorted(reduced - set(published))]
+    refused += [f"{k}: in published, not in reduced"
+                for k in sorted(set(published) - reduced)]
+    share = int(conf["deployment"].get("expert_parallel", 1))
+    if "n_routed_experts" in published:
+        # this chip holds experts [0, held) of the router's published width
+        held, width = conf["n_routed_experts"], published["n_routed_experts"]
+        if held * share != width:
+            refused.append(f"n_routed_experts = {held}: not the share of "
+                           f"{width} published experts that each of "
+                           f"deployment.expert_parallel = {share} chips holds")
+        kw["num_experts"] = width
+        if "experts_held" in fields:
+            kw["experts_held"] = held
+        else:
+            refused.append(f"experts_held = {held}: an expert share needs "
+                           "this ModelConfig field")
+    elif share != 1:
+        refused.append(f"deployment.expert_parallel = {share}: an expert "
+                       "share lists n_routed_experts in reduced")
     if "n_routed_experts" in conf:
-        if not conf.get("norm_topk_prob", False):
-            raise ValueError("the program renormalizes the top-k gates; "
-                             "a configuration it serves states "
-                             "norm_topk_prob true")
-        if conf["deployment"].get("routing") == "dropless":
+        if "norm_topk_prob" not in conf:
+            refused.append("norm_topk_prob: an MoE configuration states it")
+        if conf["deployment"].get("routing") == "dropless" and "top_k" in kw:
             # capacity = group size: n·k/E·cf >= n
-            kw["capacity_factor"] = (conf["n_routed_experts"]
-                                     / conf["num_experts_per_tok"] + 1)
-    return dataclasses.replace(cfg, **kw)
+            kw["capacity_factor"] = kw["num_experts"] / kw["top_k"] + 1
+
+    arch = conf["program"]["arch"]
+    registry = get_config(arch)
+    refused += [f"{f.name} = {getattr(registry, f.name)!r}: set by the "
+                f"registry entry {arch!r}, not stated in the file"
+                for f in fields.values()
+                if f.name not in RUNTIME_FIELDS and f.name not in kw
+                and getattr(registry, f.name) != f.default]
+    if refused:
+        raise ValueError(f"configuration {conf['name']!r} refused:\n  "
+                         + "\n  ".join(refused))
+    return dataclasses.replace(registry, **kw)
+
+
+def _same(value, implied) -> bool:
+    """Equal, with booleans kept apart from numbers."""
+    return (isinstance(value, bool) == isinstance(implied, bool)
+            and value == implied)
 
 
 def served_shapes(model) -> dict:
@@ -97,7 +174,8 @@ class System:
         shapes = served_shapes(self.model)
         self.phases = {}
         t = _clock()
-        self.weights = W.make_base(shapes, seed)
+        roles = conf.get("leaf_roles")
+        self.weights = W.make_base(shapes, seed, roles)
         jax.block_until_ready(self.weights)
         self.phases["base_s"] = _clock() - t
         t = _clock()
@@ -111,7 +189,7 @@ class System:
         self.variants = {}
         for i in range(int(dep_conf["variants"])):
             name = f"v{i}"
-            self.variants[name] = W.make_variant(shapes, seed, i)
+            self.variants[name] = W.make_variant(shapes, seed, i, roles)
             self.dep.publish(name, delta_model(self.variants[name]),
                              wait=True)
         self.phases["variants_s"] = _clock() - t

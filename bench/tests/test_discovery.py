@@ -5,7 +5,28 @@ import shutil
 
 import pytest
 
-from bench import spec
+from bench import spec, system, work
+from bench import weights as W
+from bench.tests.test_work import MOONLIGHT
+
+# Moonlight-16B-A3B as one chip's share of a 4-chip expert-parallel
+# deployment: latent attention, sigmoid routing with a selection bias, 16
+# of 64 experts a layer held here
+SHARE = dict(
+    MOONLIGHT, name="moonlight-16b-a3b.ep4",
+    source="https://huggingface.co/moonshotai/Moonlight-16B-A3B",
+    model_type="deepseek_v3", n_routed_experts=16,
+    reduced=["n_routed_experts"], published={"n_routed_experts": 64},
+    leaf_roles={"wq_b": "target", "wkv_a": "target", "wkv_b": "target",
+                "kv_norm": "norm", "e_bias": "bias"},
+    program={"arch": "deepseek-moe-16b"}, reference="deepseek_v3",
+    deployment={"chips": 1, "expert_parallel": 4, "lanes": 6,
+                "bank_size": 5, "variants": 4, "prompt_len": 256,
+                "max_len": 4096})
+# what the program lacks to serve it, each by the name it is refused under
+MISSING = {"kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+           "v_head_dim", "scoring_func", "topk_method",
+           "routed_scaling_factor", "seq_aux", "experts_held"}
 
 
 def test_every_named_file_exists():
@@ -53,6 +74,19 @@ def new_tree(tmp_path, monkeypatch):
         json.dumps({"max_logit_gap": {"limit": 0.5}}))
     (root / "bench/metrics/queue_depth.py").write_text(
         "def read(run):\n    return 7.0\n")
+    share_file = "bench/configs/moonlight-16b-a3b.ep4.json"
+    (root / share_file).write_text(json.dumps(SHARE))
+    (root / "bench/reference/deepseek_v3.py").write_text(
+        "def logits(*args):\n    raise NotImplementedError\n")
+    (root / "bench/limits/moonlight.ep4.mixed.open.json").write_text(
+        json.dumps({"max_logit_gap": {"limit": 0.3}}))
+    bench["configs"].append({"name": SHARE["name"], "source": SHARE["source"],
+                             "file": share_file,
+                             "reduced": ["n_routed_experts"], "why": "x"})
+    bench["workloads"].append({"name": "moonlight.ep4.mixed.open",
+                               "config": SHARE["name"],
+                               "traffic": "mixed.open", "chips": 1,
+                               "why": "x"})
     bench["configs"].append({"name": "tiny-dense", "source": "x",
                              "file": "bench/configs/tiny-dense.json",
                              "reduced": [], "why": "x"})
@@ -81,5 +115,21 @@ def test_a_new_cell_is_new_files_and_entries(new_tree):
     assert cell.reader("queue_depth").read(None) == 7.0
     old = spec.Cell("ds7b.mixed.open", spec.load_benchmark(root))
     assert "queue_depth" in [m["name"] for m in old.metrics(True)]
+
+    # an MLA expert share: the harness sizes it before the chip (no key is
+    # left uncounted), gives every leaf it names a role, and refuses it
+    # only where the program lacks a field, by that field's name
+    share = spec.Cell("moonlight.ep4.mixed.open", spec.load_benchmark(root))
+    conf = share.config
+    assert conf == SHARE and callable(share.reference().logits)
+    plan = work.params_bytes_summary(conf, 5, 6 * 4096)
+    assert round(plan["total"] / 1e9, 2) == 9.47
+    shapes = {f"layers.attn.{n}": (2, 64, 64) for n in conf["leaf_roles"]}
+    assert set(W.leaf_roles(shapes, conf["leaf_roles"]).values()) == {
+        "target", "norm", "bias"}
+    with pytest.raises(ValueError) as e:
+        system.program_config(conf)
+    refused = {line.split()[0] for line in str(e.value).splitlines()[1:]}
+    assert refused == MISSING
     for rel, data in before.items():
         assert (root / rel).read_bytes() == data, f"{rel} was edited"
