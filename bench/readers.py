@@ -26,3 +26,14 @@ def traced_steps(run) -> list:
         return []
     start, stop = run.trace["host_interval"]
     return [s for s in run.steps if start <= s[0] <= stop]
+
+
+def scope_ms_per_step(run, module: str, scope: str):
+    """Device milliseconds of the ops in name scope ``scope`` of program
+    ``module``, per run of that program in the trace; None where the
+    trace holds no such op."""
+    mod = (run.trace or {}).get("modules", {}).get(module)
+    seconds = (mod or {}).get("scopes", {}).get(scope)
+    if not seconds or not mod["count"]:
+        return None
+    return 1e3 * seconds / mod["count"]

@@ -11,11 +11,12 @@ seconds, stamped token by token.  Then, with the program's state freed,
 the served tokens are compared with the plain reference (``check.py``).
 
 ``--trace 1`` profiles the window's last seconds and reports the cell's
-per-layer metrics; ``--trace 0`` reports its end-to-end metrics.  The last
-line of standard output is one JSON object; the numbers compared for
-``correct`` end standard error and the result line.  Without a TPU, or
-with fewer chips than the cell asks for, the run exits non-zero and
-prints no result.
+per-layer metrics (after the window, the trace's ops are joined to the
+step programs' HLO text for their name scopes); ``--trace 0`` reports its
+end-to-end metrics.  The last line of standard output is one JSON object;
+the numbers compared for ``correct`` end standard error and the result
+line.  Without a TPU, or with fewer chips than the cell asks for, the run
+exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -63,6 +64,15 @@ class Run:
         self.__dict__.update(kw)
 
 
+def engine_counters(eng, compiles: Compiles) -> dict:
+    """Every number the engine counts (``ServingEngine.metrics``), and the
+    process's backend compiles."""
+    out = {k: v for k, v in eng.metrics.items()
+           if isinstance(v, (int, float))}
+    out["compiles"] = compiles.n
+    return out
+
+
 def peak_memory(jax) -> int:
     return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in jax.local_devices())
@@ -101,15 +111,7 @@ def execute(cell, seed: int, seconds: float, trace: bool,
         seconds=seconds, trace_dir=trace_dir,
         trace_seconds=min(TRACE_SECONDS, seconds))
     eng = system.engine
-
-    def counters():
-        out = {k: eng.metrics[k] for k in ("prefill_seconds", "prefills",
-                                           "decode_seconds",
-                                           "decode_steps")}
-        out["compiles"] = compiles.n
-        return out
-
-    rec = window.run(counters)
+    rec = window.run(lambda: engine_counters(eng, compiles))
     setup_s = rec["t0"] - T_START
     memory_peak = peak_memory(jax)
     sent = window.sent
@@ -132,7 +134,7 @@ def execute(cell, seed: int, seconds: float, trace: bool,
                           recursive=True)
         if window.trace_t is not None and files:
             start, stop = window.trace_t
-            reduced = TR.reduce(TR.load(files[0]))
+            reduced = TR.reduce(TR.load(files[0]), eng.step_hlo())
             reduced["host_interval"] = (start, stop)
         shutil.rmtree(trace_dir, ignore_errors=True)
 

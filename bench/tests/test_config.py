@@ -36,6 +36,28 @@ def _tiny_moe(**kw):
     return conf
 
 
+def program_with(monkeypatch, add=None, drop=()):
+    """Stand in for the program's ``ModelConfig``: its fields less
+    ``drop``, plus ``add`` ({field: default}); the registry's entries are
+    handed over as the stand-in.  A refusal test builds its gap here, so
+    that it still has one once the program gains a field."""
+    import repro.configs
+    from repro.configs import base
+    add = add or {}
+    kept = [(f.name, f.type, dataclasses.field(default=f.default))
+            for f in dataclasses.fields(base.ModelConfig)
+            if f.name not in drop and f.name not in add]
+    stand_in = dataclasses.make_dataclass(
+        "ModelConfig", kept + [(k, type(v), dataclasses.field(default=v))
+                               for k, v in add.items()], frozen=True)
+    names = {f.name for f in dataclasses.fields(stand_in)}
+    registry = repro.configs.get_config
+    monkeypatch.setattr(base, "ModelConfig", stand_in)
+    monkeypatch.setattr(repro.configs, "get_config", lambda arch: stand_in(
+        **{k: v for k, v in dataclasses.asdict(registry(arch)).items()
+           if k in names}))
+
+
 def _refused(conf) -> set:
     """The keys ``program_config`` refuses, one per line of its error."""
     with pytest.raises(ValueError) as e:
@@ -104,20 +126,30 @@ def test_an_moe_file_reaches_the_program():
     assert cfg.capacity_factor == 8 / 2 + 1
 
 
+# keys the program implements at one value only, made up for the test:
+# a published key may yet become a field the program takes
+EXAMPLE_IMPLIED = {"example_gate": "softmax", "example_scaling": None,
+                   "example_flag": False}
+
+
 @pytest.mark.parametrize("extra, key", [
-    ({"scoring_func": "sigmoid"}, "scoring_func"),
-    ({"rope_scaling": {"type": "yarn", "factor": 4.0}}, "rope_scaling"),
+    ({"example_gate": "sigmoid"}, "example_gate"),
+    ({"example_scaling": {"type": "yarn", "factor": 4.0}}, "example_scaling"),
     ({"frobnicate": 3}, "frobnicate"),
     ({"d_model": 64}, "d_model"),
     ({"remat": False}, "remat"),
-    ({"attention_bias": 0}, "attention_bias"),
-], ids=["scoring_func_sigmoid", "rope_scaling_non_null", "unknown_key",
-        "a_field_set_twice", "a_runtime_field", "a_number_for_a_boolean"])
-def test_program_config_refuses_by_name(extra, key):
+    ({"example_flag": 0}, "example_flag"),
+], ids=["an_implied_key_at_another_value", "an_implied_null_given_a_value",
+        "unknown_key", "a_field_set_twice", "a_runtime_field",
+        "a_number_for_a_boolean"])
+def test_program_config_refuses_by_name(extra, key, monkeypatch):
+    monkeypatch.setattr(system, "IMPLIED",
+                        dict(system.IMPLIED, **EXAMPLE_IMPLIED))
     assert _refused(dict(_tiny(), **extra)) == {key}
 
 
-def test_program_config_refuses_norm_topk_prob_false():
+def test_program_config_refuses_norm_topk_prob_false(monkeypatch):
+    program_with(monkeypatch, drop=("norm_topk_prob",))
     assert _refused(_tiny_moe(norm_topk_prob=False)) == {"norm_topk_prob"}
     conf = _tiny_moe()
     del conf["norm_topk_prob"]
@@ -148,7 +180,9 @@ def _share(held=2, chips=4):
     return conf
 
 
-def test_program_config_refuses_an_expert_share_without_experts_held():
+def test_program_config_refuses_an_expert_share_without_experts_held(
+        monkeypatch):
+    program_with(monkeypatch, drop=("experts_held",))
     assert _refused(_share()) == {"experts_held"}
     assert _refused(_share(chips=2)) == {"n_routed_experts", "experts_held"}
     conf = _tiny_moe()
@@ -157,17 +191,7 @@ def test_program_config_refuses_an_expert_share_without_experts_held():
 
 
 def test_an_expert_share_reaches_a_program_with_experts_held(monkeypatch):
-    import repro.configs
-    from repro.configs import base
-
-    @dataclasses.dataclass(frozen=True)
-    class WithShare(base.ModelConfig):
-        experts_held: int = 0
-
-    registry = repro.configs.get_config
-    monkeypatch.setattr(base, "ModelConfig", WithShare)
-    monkeypatch.setattr(repro.configs, "get_config", lambda arch: WithShare(
-        **dataclasses.asdict(registry(arch))))
+    program_with(monkeypatch, add={"experts_held": 0})
     cfg = system.program_config(_share())
     assert (cfg.num_experts, cfg.experts_held) == (8, 2)
 
@@ -176,8 +200,8 @@ def test_a_refused_file_makes_no_weight(monkeypatch):
     def made(*a, **k):
         raise AssertionError("a weight was made")
     monkeypatch.setattr(W, "make_base", made)
-    with pytest.raises(ValueError, match="scoring_func = 'sigmoid'"):
-        system.System(dict(_tiny(), scoring_func="sigmoid"), SEED)
+    with pytest.raises(ValueError, match="frobnicate = 3"):
+        system.System(dict(_tiny(), frobnicate=3), SEED)
 
 
 SHAPES = {"embed": (256, 64), "final_norm": (64,), "unembed": (256, 64),

@@ -5,7 +5,8 @@ from the seed, the window, the stamped tokens, the readers, the reference
 and the result line.  Then the timed path is broken underneath and
 ``correct`` has to come out false: once with a token altered where it is
 produced, once with a decode step that returns its state unchanged.  The
-int4 control has to fail the limit that the sound run meets.
+int4 control has to fail the limit that the sound run meets.  The mixed
+cell and the base-only cell are each driven so.
 """
 import copy
 import json
@@ -20,6 +21,7 @@ from bench import spec
 
 SEED = 2 ** 33 + 17
 CELL = "ds7b.mixed.open"
+BASE_CELL = "ds7b.base.open"
 LIMIT = 0.1             # the tiny model's sound gap reads about 0.02
 
 
@@ -93,7 +95,8 @@ def test_result_line_keys(sound):
     assert set(sound["breakdown"]) == {"device_ops", "idle_gaps"}
     assert json.loads(json.dumps(sound)) == sound
     # the counters that need no chip are read; device metrics are not
-    assert {"decode_step_ms", "prefill_stall_share"} <= set(sound["metrics"])
+    assert {"decode_step_ms", "prefill_stall_share", "engine_host_ms_per_step",
+            "prefill_rows_used_share"} <= set(sound["metrics"])
     for m in sound["metrics"].values():
         assert set(m) == {"value", "unit"}
 
@@ -116,16 +119,35 @@ def _keep_state(orig):
     return step
 
 
-@pytest.mark.parametrize("fault", [_alter_tokens, _keep_state],
-                         ids=["token_altered", "state_unchanged"])
-def test_a_broken_timed_path_is_not_correct(fault, monkeypatch):
+def _broken_run(fault, monkeypatch, cell, traffic):
     from repro.models import transformer
     monkeypatch.setattr(transformer, "decode_step",
                         fault(transformer.decode_step))
-    res = R.execute(tiny_cell(CELL, "deepseek-7b.int8", "mixed.open"),
+    res = R.execute(tiny_cell(cell, "deepseek-7b.int8", traffic),
                     SEED, 2.0, False)
     assert res["correct"] is False
     assert res["checks"]["max_logit_gap"]["value"] > LIMIT
+
+
+@pytest.mark.parametrize("fault", [_alter_tokens, _keep_state],
+                         ids=["token_altered", "state_unchanged"])
+def test_a_broken_timed_path_is_not_correct(fault, monkeypatch):
+    _broken_run(fault, monkeypatch, CELL, "mixed.open")
+
+
+@pytest.mark.parametrize("fault", [_alter_tokens, _keep_state],
+                         ids=["token_altered", "state_unchanged"])
+def test_a_broken_base_only_path_is_not_correct(fault, monkeypatch):
+    _broken_run(fault, monkeypatch, BASE_CELL, "base.open")
+
+
+def test_a_base_only_run_is_correct_and_its_control_is_not():
+    res = R.execute(tiny_cell(BASE_CELL, "deepseek-7b.int8", "base.open"),
+                    SEED, 2.0, False, control="int4")
+    gap = res["checks"]["max_logit_gap"]["value"]
+    assert res["correct"] is True and gap <= LIMIT
+    assert res["control"]["max_logit_gap"] > max(3 * gap, LIMIT)
+    assert res["control"]["correct"] is False
 
 
 def test_without_a_tpu_the_command_prints_no_result(tmp_path):

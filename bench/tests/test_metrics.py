@@ -93,10 +93,37 @@ def test_counter_readers():
     assert _read("setup_s", run) == 12.5
 
 
+ENGINE = {"prefill_rows": 3, "prefill_rows_computed": 4,
+          "host_seconds": 0.5, "decode_steps": 10}
+
+
+def test_engine_counter_readers():
+    after = {"prefill_rows": 9, "prefill_rows_computed": 12,
+             "host_seconds": 0.52, "decode_steps": 30}
+    run = _run([], before=ENGINE, after=after)
+    assert _read("engine_host_ms_per_step", run) == pytest.approx(1.0)
+    assert _read("prefill_rows_used_share", run) == pytest.approx(75.0)
+    # a window without a wave or a step has nothing to read
+    idle = _run([], before=ENGINE, after=ENGINE)
+    assert _read("engine_host_ms_per_step", idle) is None
+    assert _read("prefill_rows_used_share", idle) is None
+
+
+def test_every_engine_counter_is_read():
+    from bench import run as R
+    eng = types.SimpleNamespace(metrics=dict(
+        ENGINE, prefill_seconds=1.5, new_counter=2, label="x",
+        ttft_samples=[0.1]))
+    compiles = types.SimpleNamespace(n=4)
+    assert R.engine_counters(eng, compiles) == dict(
+        ENGINE, prefill_seconds=1.5, new_counter=2, compiles=4)
+
+
 def test_device_readers_say_nothing_without_a_chip_or_trace():
     run = _run([_request(T0, [T0 + 1])])
     for name in ("idle_share", "sign_gemm_ms_per_step",
-                 "sign_gemm_roofline.decode", "mfu.decode"):
+                 "sign_gemm_roofline.decode", "mfu.decode",
+                 "base_gemm_ms_per_step", "attention_ms_per_step"):
         assert _read(name, run) is None
 
 
@@ -115,12 +142,25 @@ def test_mfu_decode_over_step_time():
     assert _read("mfu.decode", run) == pytest.approx(100 * want / 1e12)
 
 
-def _traced_run(custom_call_s, steps):
+def _traced_run(custom_call_s, steps, **decode):
     trace = {"host_interval": (T0, T0 + 10), "idle_share": 0.25,
-             "modules": {"decode_banked_fn": {
-                 "count": len(steps), "device_s": 1.0,
-                 "custom_call_s": custom_call_s}}}
+             "modules": {"decode_banked_fn": dict(
+                 count=len(steps), device_s=1.0,
+                 custom_call_s=custom_call_s, **decode)}}
     return _run([], conf=CONF, peak=PEAK, steps=steps, trace=trace)
+
+
+def test_scope_readers_from_the_trace():
+    steps = [(T0 + 1, 0.1, {"__base__": 3}, [5, 5, 5])] * 4
+    run = _traced_run(0.004, steps, scopes={
+        "base_gemm": 0.036, "attention": 0.008, "while": 0.1})
+    assert _read("base_gemm_ms_per_step", run) == pytest.approx(9.0)
+    assert _read("attention_ms_per_step", run) == pytest.approx(2.0)
+    # a trace joined to no program text, or with no op in the scope
+    for scopes in ({}, {"scopes": {"while": 0.1}}):
+        run = _traced_run(0.004, steps, **scopes)
+        assert _read("base_gemm_ms_per_step", run) is None
+        assert _read("attention_ms_per_step", run) is None
 
 
 def test_sign_kernel_readers_from_the_trace():

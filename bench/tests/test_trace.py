@@ -89,3 +89,120 @@ def test_custom_calls_and_op_names_from_hlo_text():
     assert TR.is_custom_call(kernel) and not TR.is_custom_call(fusion)
     assert TR.op_name(kernel["name"]) == "%sign_delta_local.12"
     assert TR.op_name("custom-call.3") == "custom-call.3"
+
+
+DECODE_HLO = """HloModule jit_decode_banked_fn, is_scheduled=true
+
+%fused_computation.1 (param_0.1: bf16[6,4096]) -> bf16[6,4096] {
+  ROOT %dot.4 = bf16[6,4096]{1,0} dot(%param_0.1), metadata={op_name="jit(decode_banked_fn)/while/body/base_gemm/dot_general" stack_frame_id=6}
+}
+
+ENTRY %main.9 (p: bf16[6,4096]) -> bf16[6,4096] {
+  %fusion.1 = bf16[6,4096]{1,0} fusion(%p), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(decode_banked_fn)/while/body/closed_call/base_gemm/dot_general" stack_frame_id=6}, backend_config={"flag_configs":[]}
+  %custom-call.3 = f32[6,4096]{1,0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(decode_banked_fn)/while/body/attention/attention/while/body/dot_general"}
+  ROOT %fusion.2 = bf16[6,4096]{1,0} fusion(%custom-call.3), kind=kLoop, calls=%fused_computation.1
+}
+"""
+
+
+def _wave_hlo(rows):
+    return ("HloModule jit_prefill_banked_fn, is_scheduled=true\n\n"
+            f"ENTRY %main (p: bf16[{rows},256]) -> bf16[{rows},256] {{\n"
+            f"  ROOT %custom-call.3 = bf16[{rows},256]{{1,0}} custom-call(%p)"
+            ', metadata={op_name="jit(prefill_banked_fn)/base_gemm/x"}\n}\n')
+
+
+HLO = {"decode_banked": DECODE_HLO, "prefill_banked/r1": _wave_hlo(1),
+       "prefill_banked": _wave_hlo(6)}
+
+
+def test_name_stacks_from_hlo_text():
+    stacks = TR.name_stacks(DECODE_HLO)
+    # an instruction of a fused computation is not an op of the trace,
+    # but its name is unique in the module all the same
+    assert set(stacks) == {"dot.4", "fusion.1", "custom-call.3"}
+    assert "base_gemm" in stacks["fusion.1"]
+
+
+def test_scopes_join_ops_to_their_instructions():
+    mods = TR.reduce(synthetic(), HLO)["modules"]
+    dec = mods["decode_banked_fn"]["scopes"]
+    assert dec["base_gemm"] == pytest.approx(0.004)       # fusion.1
+    assert dec["attention"] == pytest.approx(0.005)       # custom-call.3
+    # fusion.2 (9-10 ms) has no op_name: no segment gets its time; the
+    # 1 ms in which fusion.1 and custom-call.3 overlap counts once
+    assert dec["jit(decode_banked_fn)"] == pytest.approx(0.008)
+    # the wave buckets share one module name: nothing tells their ops
+    # apart in the trace, so the waves get no scopes
+    assert "scopes" not in mods["prefill_banked_fn"]
+    assert "scopes" not in TR.reduce(synthetic())["modules"][
+        "decode_banked_fn"]
+
+
+def test_an_op_with_a_repeated_segment_is_credited_once():
+    dec = TR.reduce(synthetic(), HLO)["modules"]["decode_banked_fn"]
+    # custom-call.3's stack holds "attention", "while" and "body" twice
+    assert dec["scopes"]["attention"] == pytest.approx(0.005)
+    assert dec["scopes"]["while"] == pytest.approx(0.008)
+
+
+def test_an_op_nested_in_another_of_its_scope_adds_no_time():
+    ms = 1e6
+    events = synthetic() + [
+        # a loop of the attention scope around its body's op (the
+        # custom call at 3-8 ms): only 2 ms of the loop are its own
+        ev(TR.OPS_LINE, "%while.7 = (s32[]) while(%p)", 2 * ms, 8.5 * ms)]
+    hlo = dict(HLO, decode_banked=DECODE_HLO.replace(
+        "  ROOT %fusion.2",
+        '  %while.7 = (s32[]) while(%p), metadata={op_name="jit(decode_banke'
+        'd_fn)/while/body/attention/attention/while"}\n  ROOT %fusion.2'))
+    dec = TR.reduce(events, hlo)["modules"]["decode_banked_fn"]["scopes"]
+    assert dec["attention"] == pytest.approx(0.0065)      # 2 - 8.5 ms
+    assert dec["base_gemm"] == pytest.approx(0.004)
+    assert dec["jit(decode_banked_fn)"] == pytest.approx(0.0085)
+
+
+def test_the_scopes_of_a_compiled_program():
+    import jax
+    import jax.numpy as jnp
+
+    def f(x, w):
+        with jax.named_scope("base_gemm"):
+            y = jax.lax.optimization_barrier(x @ w)
+        with jax.named_scope("attention"):
+            return jax.nn.softmax(y, axis=-1)
+    x = jnp.ones((8, 16), jnp.float32)
+    text = jax.jit(f).lower(x, x.T).compile().as_text()
+    segments = set().union(*TR.name_stacks(text).values())
+    assert {"base_gemm", "attention"} <= segments
+
+
+def test_a_gap_is_named_by_the_span_with_most_self_time_in_it():
+    ms = 1e6
+    events = synthetic() + [
+        ev("python", "serve.step", 45 * ms, 110 * ms, plane="/host:CPU"),
+        ev("python", "serve.emit", 60 * ms, 90 * ms, plane="/host:CPU"),
+        ev("python", "serve.admit", 92 * ms, 95 * ms, plane="/host:CPU")]
+    gaps = TR.reduce(events)["idle_gaps"]
+    # in 50-100 ms serve.step covers all, serve.emit 30 ms of it, and
+    # serve.step's own time is 17 ms: the gap is serve.emit's
+    assert gaps[0] == ["serve.emit", pytest.approx(0.050)]
+    events[-2]["end"] = 70 * ms
+    assert TR.reduce(events)["idle_gaps"][0][0] == "serve.step"
+
+
+def test_load_keeps_engine_spans_with_their_stats(tmp_path):
+    import glob
+
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("serve.prefill", rows=1, bucket=1):
+        with jax.profiler.TraceAnnotation("other.span"):
+            jax.numpy.ones(3).block_until_ready()
+    jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = TR.load(path[0])
+    names = {e["name"] for e in events}
+    assert "serve.prefill" in names and "other.span" not in names
+    span = next(e for e in events if e["name"] == "serve.prefill")
+    assert span["stats"]["rows"] == 1 and span["stats"]["bucket"] == 1

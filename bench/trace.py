@@ -10,11 +10,23 @@ what a chip trace has confirmed): one plane per
 device, ``/device:TPU:<n>``, whose line ``XLA Modules`` has one event per
 executed program (named after the jitted function, e.g.
 ``jit_decode_banked_fn(...)``) and whose line ``XLA Ops`` has one event per
-operation inside it; host planes carry the benchmark's own
-``TraceAnnotation`` spans (``bench.*``).  One of them, ``bench.traced``,
-runs from the start of tracing to the window's close: it is the traced
-window on the trace's own timeline, and every device event is clipped to
-it.
+operation inside it, named by its HLO text; host planes carry the
+benchmark's own ``TraceAnnotation`` spans (``bench.*``) and the serving
+engine's (``serve.*``).  One of them, ``bench.traced``, runs from the
+start of tracing to the window's close: it is the traced window on the
+trace's own timeline, and every device event is clipped to it.
+
+No op event holds its name stack; the compiled program's text does.
+Given the programs' HLO texts, ``reduce`` joins each op to its
+instruction by name within the one text of its module and credits the
+op's device time once to each distinct segment of its ``op_name``
+(``modules[<module>]["scopes"]``).  A segment's seconds are the union of
+its ops' intervals: a loop's body ops run inside the loop's own op on
+the ``XLA Ops`` line, and an instant counts once however many ops of the
+segment cover it.  A module compiled to several texts
+under one name (the engine's admission-wave buckets all run
+``prefill_banked_fn``) cannot be told apart in the trace and gets no
+scopes.
 """
 from __future__ import annotations
 
@@ -26,12 +38,14 @@ MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 DEVICE_PREFIX = "/device:TPU:"
 WINDOW_SPAN = "bench.traced"
+HOST_SPANS = ("bench.", "serve.")
 
 
 def load(path: str) -> list:
     """Every event of the device planes' module and op lines, and every
-    ``bench.*`` host span, as {plane, line, name, start, end, stats}
-    dicts (times in ns on the trace's one timeline)."""
+    ``bench.*`` and ``serve.*`` host span, as {plane, line, name, start,
+    end, stats} dicts (times in ns on the trace's one timeline; stats
+    kept for ops and host spans)."""
     from jax.profiler import ProfileData
     out = []
     for plane in ProfileData.from_file(path).planes:
@@ -40,10 +54,10 @@ def load(path: str) -> list:
             if device and line.name not in (MODULES_LINE, OPS_LINE):
                 continue
             for ev in line.events:
-                if not device and not ev.name.startswith("bench."):
+                if not device and not ev.name.startswith(HOST_SPANS):
                     continue
                 stats = {}
-                if device and line.name == OPS_LINE:
+                if not device or line.name == OPS_LINE:
                     stats = {k: v for k, v in ev.stats
                              if isinstance(v, (str, int, float))}
                 out.append({"plane": plane.name, "line": line.name,
@@ -94,12 +108,43 @@ def _clip(events: list, lo: float, hi: float) -> list:
     return out
 
 
-def reduce(events: list) -> dict:
+# an instruction line of HLO text: "[ROOT ]%name = <shape> op(...), ...,
+# metadata={op_name="jit(f)/while/body/base_gemm/dot_general" ...}"
+_INSTRUCTION = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s+=\s.*?'
+                          r'\bmetadata=\{[^}]*?\bop_name="([^"]*)"')
+
+
+def name_stacks(hlo_text: str) -> dict:
+    """{instruction name -> the distinct segments of its ``op_name``} for
+    every instruction of one program's HLO text that has one."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            out[m.group(1)] = frozenset(m.group(2).split("/"))
+    return out
+
+
+def _stacks_by_module(hlo: dict) -> dict:
+    """{module -> name stacks} for each module that has exactly one
+    text among ``hlo``'s values."""
+    texts = collections.defaultdict(set)
+    for text in hlo.values():
+        head = text.split(None, 2)
+        if len(head) > 1 and head[0] == "HloModule":
+            texts[_module_of(head[1].rstrip(","))].add(text)
+    return {mod: name_stacks(ts.pop()) for mod, ts in texts.items()
+            if len(ts) == 1}
+
+
+def reduce(events: list, hlo: dict | None = None) -> dict:
     """Busy seconds (union of op intervals inside the traced window,
     averaged over the devices), the device's idle share of that window,
     per-module counts and device seconds, custom-call seconds per module,
     the ten ops that took most time, and the ten longest idle gaps each
-    tied to the host span that covers most of it (else "engine host")."""
+    named by the host span with most self time in it (else "engine
+    host").  With ``hlo`` (the programs' HLO texts), a module with one
+    text also gets ``scopes``: {name-stack segment -> device seconds}."""
     spans = [e for e in events if e["name"] == WINDOW_SPAN]
     if len(spans) != 1:
         raise ValueError(f"expected one {WINDOW_SPAN!r} span in the trace, "
@@ -139,15 +184,30 @@ def reduce(events: list) -> dict:
         ivs.sort()
     starts = collections.defaultdict(list, {
         k: [iv[0] for iv in ivs] for k, ivs in intervals.items()})
+    stacks = _stacks_by_module(hlo or {})
+    scopes = {mod: collections.Counter() for mod in stacks}
+    # (plane, module, segment) -> the end of the time credited to it
+    reach = collections.defaultdict(float)
     by_op = collections.Counter()
-    for op in ops:
+    for op in sorted(ops, key=lambda o: o["start"]):
         owner = op["stats"].get("hlo_module")
         owner = _module_of(owner) if owner else _enclosing(
             intervals[op["plane"]], starts[op["plane"]], op["start"])
         dur = (op["end"] - op["start"]) / 1e9
-        by_op[f"{owner}/{op_name(op['name'])}"] += dur
+        name = op_name(op["name"])
+        by_op[f"{owner}/{name}"] += dur
         if owner is not None and is_custom_call(op):
             modules[owner]["custom_call_s"] += dur
+        if owner in stacks:
+            for segment in stacks[owner].get(name.lstrip("%"), ()):
+                key = (op["plane"], owner, segment)
+                start = max(op["start"], reach[key])
+                if op["end"] > start:
+                    scopes[owner][segment] += (op["end"] - start) / 1e9
+                    reach[key] = op["end"]
+    for mod, seconds in scopes.items():
+        if mod in modules:
+            modules[mod]["scopes"] = dict(seconds)
 
     longest = sorted(gaps, reverse=True)[:10]
     idle = [[_host_cause(host, s, e), dur / 1e9] for dur, s, e in longest]
@@ -166,9 +226,16 @@ def _enclosing(spans: list, starts: list, t: float):
 
 
 def _host_cause(host: list, s: float, e: float) -> str:
-    best, cover = "engine host", 0.0
-    for h in host:
-        c = min(e, h["end"]) - max(s, h["start"])
-        if c > cover:
-            best, cover = h["name"], c
-    return best
+    """The host span with most self time in [s, e]: each instant goes to
+    the shortest span that covers it, as a span nested in another is
+    shorter than it."""
+    inside = [h for h in host if h["end"] > s and h["start"] < e]
+    cuts = sorted({s, e} | {t for h in inside for t in (h["start"], h["end"])
+                            if s < t < e})
+    own = collections.Counter()
+    for a, b in zip(cuts, cuts[1:]):
+        cover = [h for h in inside if h["start"] <= a and h["end"] >= b]
+        if cover:
+            own[min(cover, key=lambda h: h["end"] - h["start"])["name"]] += (
+                b - a)
+    return own.most_common(1)[0][0] if own else "engine host"
